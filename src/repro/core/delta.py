@@ -27,12 +27,12 @@ from . import quantize
 from .types import DeltaStore, INVALID_ID, IVFIndex, normalize_if_cosine
 
 
-def _tombstone_main(index: IVFIndex, ids: jax.Array):
+def _tombstone_main(row_ids, valid, counts, ids: jax.Array):
     """Invalidate any main-partition rows whose id appears in `ids`."""
-    hit = (index.ids[:, :, None] == ids[None, None, :]).any(-1)  # [k, p_max]
-    hit = hit & index.valid
-    new_valid = index.valid & ~hit
-    new_counts = index.counts - hit.sum(-1).astype(index.counts.dtype)
+    hit = (row_ids[:, :, None] == ids[None, None, :]).any(-1)  # [k, p_max]
+    hit = hit & valid
+    new_valid = valid & ~hit
+    new_counts = counts - hit.sum(-1).astype(counts.dtype)
     return new_valid, new_counts
 
 
@@ -41,7 +41,9 @@ def _tombstone_delta(delta: DeltaStore, ids: jax.Array):
     return delta.valid & ~hit
 
 
-@jax.jit
+# The jitted writes take only the leaves they read. A jit over the whole
+# IVFIndex would hand back fresh copies of the untouched vector and code
+# tiers, doubling their device memory on every write.
 def upsert(index: IVFIndex, vecs: jax.Array, ids: jax.Array,
            attrs: jax.Array) -> IVFIndex:
     """Insert a batch of [B] rows with upsert semantics.
@@ -49,21 +51,29 @@ def upsert(index: IVFIndex, vecs: jax.Array, ids: jax.Array,
     Precondition (enforced by the host wrapper, which flushes first if
     needed): delta.count + B <= delta capacity.
     """
-    cfg = index.config
-    vecs = normalize_if_cosine(vecs.astype(jnp.float32), cfg.metric)
+    valid, counts, delta = _upsert(
+        index.ids, index.valid, index.counts, index.delta, index.qstats,
+        vecs, ids, attrs, metric=index.config.metric)
+    return dataclasses.replace(index, valid=valid, counts=counts,
+                               delta=delta)
+
+
+@partial(jax.jit, static_argnames=("metric",))
+def _upsert(row_ids, valid, counts, d: DeltaStore, qstats, vecs, ids,
+            attrs, *, metric: str):
+    vecs = normalize_if_cosine(vecs.astype(jnp.float32), metric)
     B = vecs.shape[0]
-    d = index.delta
 
     # 1. upsert semantics: tombstone any existing copies
-    new_valid, new_counts = _tombstone_main(index, ids)
+    new_valid, new_counts = _tombstone_main(row_ids, valid, counts, ids)
     dvalid = _tombstone_delta(d, ids)
 
     # 2. append at the write cursor (quantized tier: encode on insert, so
     # flush_delta can move codes verbatim instead of re-deriving them)
     slots = d.count + jnp.arange(B, dtype=jnp.int32)
     new_codes = d.codes
-    if index.qstats is not None and d.codes is not None:
-        new_codes = d.codes.at[slots].set(quantize.encode(index.qstats, vecs))
+    if qstats is not None and d.codes is not None:
+        new_codes = d.codes.at[slots].set(quantize.encode(qstats, vecs))
     new_delta = DeltaStore(
         vectors=d.vectors.at[slots].set(vecs),
         ids=d.ids.at[slots].set(ids.astype(jnp.int32)),
@@ -72,18 +82,22 @@ def upsert(index: IVFIndex, vecs: jax.Array, ids: jax.Array,
         count=d.count + B,
         codes=new_codes,
     )
-    return dataclasses.replace(index, valid=new_valid, counts=new_counts,
-                               delta=new_delta)
+    return new_valid, new_counts, new_delta
+
+
+def delete(index: IVFIndex, ids: jax.Array) -> IVFIndex:
+    """Tombstone a batch of asset ids (no-op for unknown ids)."""
+    valid, counts, dvalid = _delete(index.ids, index.valid, index.counts,
+                                    index.delta, ids)
+    return dataclasses.replace(
+        index, valid=valid, counts=counts,
+        delta=dataclasses.replace(index.delta, valid=dvalid))
 
 
 @jax.jit
-def delete(index: IVFIndex, ids: jax.Array) -> IVFIndex:
-    """Tombstone a batch of asset ids (no-op for unknown ids)."""
-    new_valid, new_counts = _tombstone_main(index, ids)
-    dvalid = _tombstone_delta(index.delta, ids)
-    return dataclasses.replace(
-        index, valid=new_valid, counts=new_counts,
-        delta=dataclasses.replace(index.delta, valid=dvalid))
+def _delete(row_ids, valid, counts, d: DeltaStore, ids):
+    new_valid, new_counts = _tombstone_main(row_ids, valid, counts, ids)
+    return new_valid, new_counts, _tombstone_delta(d, ids)
 
 
 def delta_only_upsert(delta: DeltaStore, vecs: jax.Array, ids: jax.Array,

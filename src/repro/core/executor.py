@@ -73,7 +73,7 @@ from ..obs import trace as obs_trace
 from .hybrid import compile_filter
 from .query import QuerySpec, ResultSet
 from .topk import dedup_by_id, mask_scores, merge_topk, topk_smallest
-from .types import (INVALID_ID, MASKED_SCORE, IVFIndex, PagedIndex,
+from .types import (EXACT, INVALID_ID, MASKED_SCORE, IVFIndex, PagedIndex,
                     SearchResult, normalize_if_cosine, pairwise_scores,
                     register_dataclass, static_field)
 
@@ -289,7 +289,7 @@ def _xla_scan_gathered(queries, pv, pok, pid, k_out, *, metric, qsel=None,
         pok = pok & attr_filter(pattrs)
     n, p_max, d = pv.shape
     flat_v = pv.reshape(n * p_max, d)
-    dots = queries @ flat_v.T                       # [Q, n*p_max]
+    dots = jnp.matmul(queries, flat_v.T, precision=EXACT)   # [Q, n*p_max]
     if metric in ("ip", "cosine"):
         scores = -dots
     else:
@@ -373,7 +373,7 @@ def _int_domain_dots(q_i8, alpha, beta, flat_c):
     if d <= 1024:
         acc = jax.lax.dot_general(
             q_i8.astype(jnp.float32), flat_c.astype(jnp.float32),
-            (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST)
+            (((1,), (1,)), ((), ())), precision=EXACT)
     else:
         acc = jax.lax.dot_general(
             q_i8, flat_c, (((1,), (1,)), ((), ())),
@@ -438,7 +438,7 @@ def _delta_candidates_from(delta, metric: str, q: jax.Array,
     """Delta partition, always scanned (§3.6), in rank convention. Shared
     by the resident and the paged epilogue (the delta stays resident in
     both modes -- it is small and write-hot)."""
-    dots = q @ delta.vectors.T                       # [Q, cap]
+    dots = jnp.matmul(q, delta.vectors.T, precision=EXACT)  # [Q, cap]
     if metric in ("ip", "cosine"):
         scores = -dots
     else:
@@ -480,7 +480,7 @@ def _rescore_exact(q, v, got, ids, k_out: int, metric: str):
     """Shared exact-rescore stage of both rerank paths (resident device
     gather and paged disk gather): one op sequence, so XLA emits the same
     floats for both -- the other structural half of paged bit-parity."""
-    dots = jnp.einsum("qd,qcd->qc", q, v)
+    dots = jnp.einsum("qd,qcd->qc", q, v, precision=EXACT)
     if metric in ("ip", "cosine"):
         s = -dots
     else:
@@ -578,7 +578,7 @@ def execute_plan(index: IVFIndex, plan: QueryPlan,
             if d <= 1024:
                 acc = jnp.einsum("tqd,qnpd->tqnp", qt.astype(jnp.float32),
                                  pc.astype(jnp.float32),
-                                 precision=jax.lax.Precision.HIGHEST)
+                                 precision=EXACT)
             else:
                 acc = jnp.einsum("tqd,qnpd->tqnp", qt, pc,
                                  preferred_element_type=jnp.int32
@@ -604,7 +604,7 @@ def execute_plan(index: IVFIndex, plan: QueryPlan,
             s, i = _rerank_float32(index, q, cand_rows, k_scan)
         else:
             pv = index.vectors[parts]                 # [Q, n, p_max, d]
-            dots = jnp.einsum("qd,qnpd->qnp", q, pv)
+            dots = jnp.einsum("qd,qnpd->qnp", q, pv, precision=EXACT)
             if cfg.metric in ("ip", "cosine"):
                 scores = -dots
             else:
@@ -700,10 +700,7 @@ def _run_spec(index, queries, qmask, spec: QuerySpec):
 def compile_cache_size() -> int:
     """Live jit cache entries of the spec entry point (observability:
     MicroNN.stats() reports it next to trace_count())."""
-    try:
-        return int(_run_spec._cache_size())
-    except AttributeError:      # older jax without _cache_size
-        return trace_count()
+    return int(_run_spec._cache_size())
 
 
 def _bucket(n: int) -> int:

@@ -51,20 +51,21 @@ def pack_partitions(
     val = np.zeros((k, p_max), bool)
     cod = None if codes is None else np.zeros((k, p_max, d), np.int8)
 
+    if n and counts.max() > p_max:  # only on incremental appends
+        raise ValueError(f"partition {int(np.argmax(counts))} overflows "
+                         f"p_max={p_max}")
+    # rows in stable partition order; a row's slot is its rank within its
+    # partition, so one fancy-index scatter per tier packs the layout
     order = np.argsort(assign, kind="stable")
-    slot = np.zeros(k, np.int64)
-    for row in order:
-        p = assign[row]
-        s = slot[p]
-        if s >= p_max:  # overflow can only happen on incremental appends
-            raise ValueError(f"partition {p} overflows p_max={p_max}")
-        vec[p, s] = X[row]
-        vid[p, s] = ids[row]
-        vat[p, s] = attrs[row]
-        val[p, s] = True
-        if cod is not None:
-            cod[p, s] = codes[row]
-        slot[p] = s + 1
+    part = np.asarray(assign)[order]
+    starts = np.cumsum(counts) - counts
+    slot = np.arange(n) - starts[part]
+    vec[part, slot] = X[order]
+    vid[part, slot] = ids[order]
+    vat[part, slot] = attrs[order]
+    val[part, slot] = True
+    if cod is not None:
+        cod[part, slot] = codes[order]
     return vec, vid, vat, val, counts, cod
 
 
@@ -104,6 +105,7 @@ def build_index(
         X, ids, attrs, assign, k, pad_to=effective_pad_to(cfg), codes=codes)
 
     n_attr = vat.shape[-1]
+    code_tier = None if cod is None else jnp.asarray(cod)
     return IVFIndex(
         centroids=jnp.asarray(centroids),
         csizes=jnp.asarray(csizes, jnp.float32),
@@ -115,10 +117,10 @@ def build_index(
         delta=DeltaStore.empty(cfg.delta_capacity, X.shape[1], n_attr,
                                quantized=cod is not None),
         base_mean_size=jnp.asarray(counts.mean() if n else 0.0, jnp.float32),
-        codes=None if cod is None else jnp.asarray(cod),
+        codes=code_tier,
         qstats=qstats,
-        code_norms=None if cod is None else quantize.row_norms(
-            qstats, jnp.asarray(cod)),
+        code_norms=None if cod is None else quantize.row_norms(qstats,
+                                                               code_tier),
         drift=jnp.zeros((k,), jnp.float32),
         config=cfg,
     )

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .types import IVFConfig, normalize_if_cosine, pairwise_scores
+from .types import EXACT, IVFConfig, normalize_if_cosine, pairwise_scores
 
 
 @partial(jax.jit, static_argnames=("balance_weight", "target_size"))
@@ -76,7 +76,7 @@ def assign_minibatch(
     # Grouped running-mean update (telescoped lines 10-13).
     onehot = jax.nn.one_hot(assign, centroids.shape[0], dtype=batch.dtype)  # [s, k]
     batch_counts = onehot.sum(axis=0)                       # m_c
-    batch_sums = onehot.T @ batch                           # [k, d]
+    batch_sums = jnp.matmul(onehot.T, batch, precision=EXACT)  # [k, d]
     new_counts = counts + batch_counts
     denom = jnp.maximum(new_counts, 1.0)[:, None]
     new_centroids = (counts[:, None] * centroids + batch_sums) / denom

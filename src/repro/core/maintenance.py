@@ -231,6 +231,7 @@ def flush_delta(index: IVFIndex, max_rows: Optional[int] = None,
                           + len(touched) * d * 4),
         p_max_before=p_max, p_max_after=new_p_max)
 
+    codes = jnp.asarray(cod) if quantized else None
     new_index = IVFIndex(
         centroids=jnp.asarray(cent),
         csizes=jnp.asarray(csizes),
@@ -240,9 +241,9 @@ def flush_delta(index: IVFIndex, max_rows: Optional[int] = None,
         delta=compact_delta(index.delta, deferred, index.n_attr, quantized,
                             index.qstats),
         base_mean_size=index.base_mean_size,
-        codes=jnp.asarray(cod) if quantized else None,
+        codes=codes,
         qstats=index.qstats,
-        code_norms=quantize.row_norms(index.qstats, jnp.asarray(cod))
+        code_norms=quantize.row_norms(index.qstats, codes)
         if quantized else None,
         drift=jnp.asarray(drift),
         config=cfg)
@@ -591,14 +592,15 @@ def apply_plan(index: IVFIndex, plan: RepairPlan) -> IVFIndex:
         csz[p] = plan.csizes[j]
         drift[p] = 0.0
 
+    codes = jnp.asarray(cod) if quantized else None
     return dataclasses.replace(
         index,
         centroids=jnp.asarray(cent), csizes=jnp.asarray(csz),
         vectors=jnp.asarray(vec), ids=jnp.asarray(vid),
         attrs=jnp.asarray(vat), valid=jnp.asarray(val),
         counts=jnp.asarray(counts),
-        codes=jnp.asarray(cod) if quantized else None,
-        code_norms=quantize.row_norms(index.qstats, jnp.asarray(cod))
+        codes=codes,
+        code_norms=quantize.row_norms(index.qstats, codes)
         if quantized else None,
         drift=jnp.asarray(drift))
 

@@ -85,6 +85,20 @@ class WorkItem:
     priority: float
 
 
+def _nn_spacing(cents: np.ndarray, block: int = 1024) -> float:
+    """Mean distance from each centroid to its nearest other centroid,
+    in row blocks of the [k, k] distance matrix (O(block * k) memory: a
+    full [k, k, d] difference tensor is 51 GB at k=10,000, d=128)."""
+    c = np.asarray(cents, np.float64)
+    n2 = (c * c).sum(-1)
+    best = np.empty(len(c))
+    for s in range(0, len(c), block):
+        d2 = n2[s:s + block, None] + n2[None, :] - 2.0 * (c[s:s + block] @ c.T)
+        np.fill_diagonal(d2[:, s:s + block], np.inf)
+        best[s:s + block] = d2.min(axis=1)
+    return float(np.sqrt(np.maximum(best, 0.0)).mean())
+
+
 class IndexMonitor:
     def __init__(self, cfg: MonitorConfig | None = None):
         self.cfg = cfg or MonitorConfig()
@@ -185,11 +199,7 @@ class IndexMonitor:
             cents = np.asarray(index.centroids)
             live = counts > 0
             if live.sum() > 1:
-                d2 = ((cents[:, None, :] - cents[None, :, :]) ** 2).sum(-1)
-                d2[~live, :] = np.inf
-                d2[:, ~live] = np.inf
-                np.fill_diagonal(d2, np.inf)
-                spacing = float(np.sqrt(d2.min(axis=1)[live]).mean())
+                spacing = _nn_spacing(cents[live])
                 bar = cfg.drift_recluster_threshold * max(spacing, 1e-12)
                 for p in np.nonzero(live & (drift[:k] >= bar))[0]:
                     items.append(WorkItem(

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .types import normalize_if_cosine, register_dataclass
+from .types import EXACT, normalize_if_cosine, register_dataclass
 
 # Number of representable levels: codes span [-128, 127] <-> [0, 255].
 LEVELS = 255
@@ -152,16 +152,19 @@ def fold_queries(stats: QuantStats, q: jax.Array):
     alpha = jnp.concatenate([alpha1, alpha2], axis=0)  # [2Q]
     beta = 128.0 * (alpha1 * jnp.sum(q1.astype(jnp.float32), axis=-1)
                     + alpha2 * jnp.sum(q2.astype(jnp.float32), axis=-1)) \
-        + q @ stats.lo
+        + jnp.matmul(q, stats.lo, precision=EXACT)
     return q_i8, alpha, beta
 
 
+@jax.jit
 def row_norms(stats: QuantStats, codes: jax.Array) -> jax.Array:
     """[..., p, d] int8 codes -> [..., p] f32 squared reconstruction norms
     ||decode(c)||^2 -- the l2 scan's per-row constant, precomputed once at
     (re)pack time so the int8-domain scan never re-decodes the code tier
     (IVFIndex.code_norms). The in-scan fallback (paged frames) computes
-    the same decode-then-reduce expression, so the two agree bitwise."""
+    the same decode-then-reduce expression, so the two agree bitwise.
+    Jitted so the decode fuses into the reduction: a whole tier's f32
+    decode would hold four times the codes' bytes in device memory."""
     v = decode(stats, codes)
     return jnp.sum(v * v, axis=-1)
 

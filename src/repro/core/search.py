@@ -19,7 +19,8 @@ Faithful structure (now encoded as specs -> plans):
 Attribute post-filtering is fused *before* the top-k, reproducing the
 paper's optimization: "vectors in the requested partitions that don't
 satisfy the predicate filter are filtered before being considered in the
-top-K computation" (§3.5) -- inside the kernel on the Pallas backend.
+top-K computation" (§3.5) -- folded into the scan's per-slot id stream
+on the Pallas backend.
 """
 from __future__ import annotations
 

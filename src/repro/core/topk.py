@@ -80,8 +80,7 @@ def tournament_merge(scores: jax.Array, ids: jax.Array, k: int, axis_name: str):
     parallel heap merge", and cheaper on ICI than a flat all-gather when
     world size is large.
     """
-    size = (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-            else int(jax.lax.psum(1, axis_name)))  # 0.4.x: constant-folds
+    size = jax.lax.axis_size(axis_name)
     assert size & (size - 1) == 0, "hypercube merge needs a power-of-2 axis"
     step = 1
     while step < size:

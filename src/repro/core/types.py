@@ -34,6 +34,10 @@ METRICS = ("l2", "ip", "cosine")
 INVALID_ID = -1
 # Score assigned to masked rows so they never enter a top-k.
 MASKED_SCORE = jnp.finfo(jnp.float32).max
+# Contract precision of every f32 dot whose result is reported or ranks
+# the exact answer. A TPU's DEFAULT is one bf16 pass (~4e-3 relative);
+# HIGHEST keeps those dots f32-exact, as the CPU backend computes them.
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def register_dataclass(cls):
@@ -252,7 +256,7 @@ def pairwise_scores(q: jax.Array, v: jax.Array, metric: str) -> jax.Array:
     L2 uses the matmul expansion ||q-v||^2 = ||q||^2 + ||v||^2 - 2 q.v so the
     MXU does the heavy lifting (paper §3.3's SIMD batching, TPU-native).
     """
-    dots = q @ v.T
+    dots = jnp.matmul(q, v.T, precision=EXACT)
     if metric in ("ip", "cosine"):
         return -dots
     q2 = jnp.sum(q * q, axis=-1, keepdims=True)

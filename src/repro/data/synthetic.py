@@ -69,7 +69,10 @@ def exact_gt(X: np.ndarray, Q: np.ndarray, k: int, metric: str,
         for i in range(0, len(X), chunk):
             scores[:, i:i + chunk] = \
                 x2[None, i:i + chunk] - 2.0 * (Q @ X[i:i + chunk].T)
-    return np.argsort(scores, axis=1)[:, :k]
+    # partition out the k best per row, then order just those
+    top = np.argpartition(scores, k - 1, axis=1)[:, :k]
+    order = np.argsort(np.take_along_axis(scores, top, axis=1), axis=1)
+    return np.take_along_axis(top, order, axis=1)
 
 
 def recall(ids: np.ndarray, gt_rows: np.ndarray, row_ids: np.ndarray,

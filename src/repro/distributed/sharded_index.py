@@ -33,22 +33,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core import executor
 from ..core import topk as topk_lib
 from ..core.query import Q, QuerySpec, ResultSet
-from ..core.types import IVFIndex, SearchResult, normalize_if_cosine
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map (>=0.5, check_vma) vs experimental shard_map
-    (0.4.x, check_rep) compatibility."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+from ..core.types import (EXACT, IVFIndex, SearchResult,
+                          normalize_if_cosine)
 
 
 def index_shardings(index: IVFIndex, mesh: Mesh, model_axis: str = "model"):
@@ -83,6 +69,7 @@ def index_shardings(index: IVFIndex, mesh: Mesh, model_axis: str = "model"):
         codes=ns(m, None, None) if quantized else None,
         qstats=qstats_ns,
         code_norms=ns(m, None) if quantized else None,
+        drift=None if index.drift is None else ns(m),
         config=index.config if not isinstance(index, IVFIndex) else
         index.config,
     )
@@ -119,14 +106,14 @@ def distributed_query(
         "shard_map bodies run the XLA backend"
     cfg = index.config
     k, n_probe = spec.k, spec.n_probe
-    m_size = mesh.devices.shape[list(mesh.axis_names).index(model_axis)]
-    cap = local_cap or n_probe        # worst case: all probes on one shard
 
     def local(centroids, csizes, vectors, ids, attrs, valid, counts,
               dvec, dids, dattrs, dvalid, dcount, base, q):
         del csizes, attrs, dattrs, base
         me = jax.lax.axis_index(model_axis)
         k_local = vectors.shape[0]
+        # worst case: every probe of the local batch lands on this shard
+        cap = local_cap or min(k_local, q.shape[0] * n_probe)
         q = normalize_if_cosine(q.astype(jnp.float32), cfg.metric)
 
         # -- phase 1: local centroid scores --------------------------------
@@ -169,7 +156,7 @@ def distributed_query(
             qsel=sel & pvalid_probe[None, :], backend="xla")
 
         # delta partition: replicated, scanned once on shard 0 of the axis
-        ddots = q @ dvec.T
+        ddots = jnp.matmul(q, dvec.T, precision=EXACT)
         dsc = -ddots if cfg.metric in ("ip", "cosine") else \
             jnp.sum(dvec * dvec, -1)[None] - 2.0 * ddots
         dok = dvalid[None, :] & (me == 0)
@@ -195,8 +182,9 @@ def distributed_query(
         P(None, None), P(None), P(None, None), P(None), P(),
         P(), dp,
     )
-    fs, fi = _shard_map(
-        local, mesh, in_specs, (dp, dp),
+    fs, fi = jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=(dp, dp),
+        check_vma=False,
     )(index.centroids, index.csizes, index.vectors, index.ids, index.attrs,
       index.valid, index.counts, index.delta.vectors, index.delta.ids,
       index.delta.attrs, index.delta.valid, index.delta.count,

@@ -14,12 +14,14 @@ maintenance, where counts are frozen for the duration of a batch.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ivf_scan import default_interpret
 
 
 def _assign_kernel(x_ref, c_ref, penalty_ref, out_i_ref, out_d_ref,
@@ -62,7 +64,7 @@ def kmeans_assign(
     target_size: int = 100,
     scale: float = 1.0,
     tile_k: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,   # None: auto by backend
 ) -> Tuple[jax.Array, jax.Array]:
     """-> (assign [s] int32, best penalised cost [s] f32).
 
@@ -70,6 +72,8 @@ def kmeans_assign(
     is folded into a per-centroid penalty vector on the host side so the
     kernel streams exactly two operand tiles per grid step.
     """
+    if interpret is None:
+        interpret = default_interpret()
     s, d = batch.shape
     k = centroids.shape[0]
     penalty = counts.astype(jnp.float32) * (

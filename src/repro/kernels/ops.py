@@ -48,7 +48,7 @@ def scan_topk_mqo(queries, vectors, valid, ids, part_ids, qsel,
                                    "tile_k", "interpret"))
 def assign_nearest(batch, centroids, counts, *, balance_weight: float = 0.0,
                    target_size: int = 100, scale: float = 1.0,
-                   tile_k: int = 256, interpret: bool = True):
+                   tile_k: int = 256, interpret: Optional[bool] = None):
     """Penalised nearest-centroid assignment (Alg. 1 NEAREST, batch form)."""
     return _km.kmeans_assign(batch, centroids, counts,
                              balance_weight=balance_weight,

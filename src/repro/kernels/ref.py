@@ -15,7 +15,8 @@ MASKED = jnp.finfo(jnp.float32).max
 
 def scores_ref(q: jax.Array, v: jax.Array, metric: str) -> jax.Array:
     """q: [Q, d], v: [N, d] -> [Q, N]."""
-    dots = q.astype(jnp.float32) @ v.astype(jnp.float32).T
+    dots = jnp.matmul(q.astype(jnp.float32), v.astype(jnp.float32).T,
+                      precision=jax.lax.Precision.HIGHEST)
     if metric in ("ip", "cosine"):
         return -dots
     v2 = jnp.sum(v.astype(jnp.float32) ** 2, axis=-1)

@@ -40,9 +40,9 @@ On a real TPU the int8 tile minimum is (32, 128); p_max must be a
 multiple of 32 when running compiled (core/types.effective_pad_to bumps
 the build-time padding automatically; sq_scan_topk asserts it so a
 mis-padded layout fails loud instead of mis-compiling). The folded query
-block is int8 too, so compiled runs pad Q up to the 32-sublane minimum
-internally and slice the outputs back. Interpret mode (anything that is
-not a TPU backend) has no such constraint.
+block is int8 too, so Q is padded up to the 32-sublane minimum
+internally and the outputs sliced back; interpret mode runs the same
+padded grid.
 
 Frame-indirect entry (storage/pager.py): `codes` may be the pager's
 frame *pool* [F, p_max, d] rather than the full code tier, with
@@ -61,7 +61,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import quantize
-from .ivf_scan import MASKED, _merge_topk, default_interpret
+from .ivf_scan import (MASKED, default_interpret, merge_tile, probe_ids,
+                       qsel_rows, query_tiling, selected)
 
 # Minimum second-to-last tile dimension for int8 operands on real TPU
 # hardware (the (32, 128) tile); interpret mode is unconstrained.
@@ -70,57 +71,49 @@ INT8_SUBLANE_MIN = 32
 
 def _sq_scan_kernel(part_ids_ref,              # scalar prefetch [n]
                     *refs,
-                    k_out: int, metric: str, mqo: bool, attr_filter,
-                    has_norms: bool):
+                    k_out: int, metric: str, mqo: bool, has_norms: bool):
+    del part_ids_ref                 # consumed by the codes' index_map
     refs = list(refs)
-    q_ref, alpha_ref, beta_ref, lo_ref, scale_ref, c_ref, valid_ref, \
-        ids_ref, qsel_ref = refs[:9]
-    rest = refs[9:]
+    q_ref, alpha_ref, beta_ref, lo_ref, scale_ref, c_ref, ids_ref = refs[:7]
+    rest = refs[7:]
+    qsel_ref = rest.pop(0) if mqo else None
     norms_ref = rest.pop(0) if has_norms else None
-    attrs_ref = rest.pop(0) if attr_filter is not None else None
     out_s_ref, out_i_ref, run_s, run_i = rest
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
+    i = pl.program_id(1)
+    n = pl.num_programs(1)
 
     @pl.when(i == 0)
     def _init():
         run_s[...] = jnp.full_like(run_s, MASKED)
         run_i[...] = jnp.full_like(run_i, -1)
 
-    # integer-domain accumulation: int8 x int8 -> int32 on the MXU over
-    # the stacked [q1; q2] two-term query block
-    acc = jax.lax.dot_general(q_ref[...], c_ref[0],
-                              (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.int32)
+    # integer-domain accumulation: int8 x int8 -> int32 on the MXU, one
+    # product per term of the two-term query fold ([2, qt, d] block)
+    c = c_ref[0]
+
+    def term(t):
+        acc = jax.lax.dot_general(q_ref[t], c, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.int32)
+        return alpha_ref[t] * acc.astype(jnp.float32)   # [qt, p_max]
+
     # rank-1 affine epilogue: dots ~= alpha1*(q1.c) + alpha2*(q2.c) + beta
-    terms = alpha_ref[...] * acc.astype(jnp.float32)   # [2*q_pad, p_max]
-    qp = terms.shape[0] // 2
-    dots = terms[:qp] + terms[qp:] + beta_ref[...]
+    dots = term(0) + term(1) + beta_ref[...]
     if metric == "l2":
         if has_norms:
-            v2 = norms_ref[0]                        # precomputed tier
+            v2 = norms_ref[0]                        # [1, p_max] precomputed
         else:
             # paged fallback: decode-and-reduce, the exact expression
             # code_norms was precomputed with (bitwise-identical values)
-            c = c_ref[0].astype(jnp.float32)
-            v = (c + 128.0) * scale_ref[0][None, :] + lo_ref[0][None, :]
-            v2 = jnp.sum(v * v, axis=-1)
-        scores = v2[None, :] - 2.0 * dots
+            v = (c.astype(jnp.float32) + 128.0) * scale_ref[...] + lo_ref[...]
+            v2 = jnp.sum(v * v, axis=-1)[None, :]
+        scores = v2 - 2.0 * dots
     else:
         scores = -dots
-    ok = valid_ref[0][None, :] != 0                  # [1, p_max]
-    if attr_filter is not None:
-        ok = ok & attr_filter(attrs_ref[0])[None, :]
+    ids_row = ids_ref[0]                             # [1, p_max]
+    ok = ids_row != -1
     if mqo:
-        ok = ok & (qsel_ref[:, i][:, None] != 0)     # [Q, 1]
-    scores = jnp.where(ok, scores, MASKED)
-    cand_i = jnp.broadcast_to(ids_ref[0][None, :], scores.shape)
-    cand_i = jnp.where(scores >= MASKED, -1, cand_i)
-
-    new_s, new_i = _merge_topk(run_s[...], run_i[...], scores, cand_i,
-                               k_out)
-    run_s[...] = new_s
-    run_i[...] = new_i
+        ok = ok & selected(qsel_ref[0, 0])           # [qt, 1]
+    merge_tile(run_s, run_i, scores, ok, ids_row, k_out)
 
     @pl.when(i == n - 1)
     def _out():
@@ -152,9 +145,8 @@ def sq_scan_topk(
         f"(got {p_max}); build with pad_to=32 (types.effective_pad_to)"
     q_n = queries.shape[0]
     n = part_ids.shape[0]
+    part_ids = part_ids.astype(jnp.int32)
     mqo = qsel is not None
-    if qsel is None:
-        qsel = jnp.ones((q_n, n), jnp.int8)
 
     # fold the query block into the int8 domain ONCE per scan; the fold
     # is the stacked two-term form ([q1; q2], [alpha1; alpha2], beta)
@@ -163,65 +155,55 @@ def sq_scan_topk(
     q_i8, alpha, beta = quantize.fold_queries(stats, queries)
 
     # compiled int8 operands tile at 32 sublanes: pad Q up, slice back.
-    # Each term's half pads independently so the kernel's [:qp]/[qp:]
-    # split still lands on the term boundary.
-    q_pad = q_n
-    if not interpret and q_n % INT8_SUBLANE_MIN:
-        q_pad = -(-q_n // INT8_SUBLANE_MIN) * INT8_SUBLANE_MIN
-        padw = [(0, q_pad - q_n), (0, 0)]
-        q_i8 = jnp.concatenate([jnp.pad(q_i8[:q_n], padw),
-                                jnp.pad(q_i8[q_n:], padw)])
-        alpha = jnp.concatenate([jnp.pad(alpha[:q_n], padw[:1]),
-                                 jnp.pad(alpha[q_n:], padw[:1])])
-        beta = jnp.pad(beta, padw[:1])
-        qsel = jnp.pad(qsel, padw)
+    # The fold's two terms become a leading axis, so a query tile carries
+    # both of its terms.
+    q_pad, qt = query_tiling(q_n, INT8_SUBLANE_MIN)
+    padw = [(0, 0), (0, q_pad - q_n), (0, 0)]
+    q_i8 = jnp.pad(q_i8.reshape(2, q_n, d), padw)
+    alpha = jnp.pad(alpha.reshape(2, q_n, 1), padw)
+    beta = jnp.pad(beta.reshape(q_n, 1), padw[1:])
 
     has_norms = norms is not None and metric == "l2"
     in_specs = [
-        pl.BlockSpec((2 * q_pad, d), lambda i, pids: (0, 0)),
-        pl.BlockSpec((2 * q_pad, 1), lambda i, pids: (0, 0)),
-        pl.BlockSpec((q_pad, 1), lambda i, pids: (0, 0)),
-        pl.BlockSpec((1, d), lambda i, pids: (0, 0)),
-        pl.BlockSpec((1, d), lambda i, pids: (0, 0)),
-        pl.BlockSpec((1, p_max, d), lambda i, pids: (pids[i], 0, 0)),
-        pl.BlockSpec((1, p_max), lambda i, pids: (pids[i], 0)),
-        pl.BlockSpec((1, p_max), lambda i, pids: (pids[i], 0)),
-        pl.BlockSpec((q_pad, n), lambda i, pids: (0, 0)),
+        pl.BlockSpec((2, qt, d), lambda b, i, pids: (0, b, 0)),
+        pl.BlockSpec((2, qt, 1), lambda b, i, pids: (0, b, 0)),
+        pl.BlockSpec((qt, 1), lambda b, i, pids: (b, 0)),
+        pl.BlockSpec((1, d), lambda b, i, pids: (0, 0)),
+        pl.BlockSpec((1, d), lambda b, i, pids: (0, 0)),
+        pl.BlockSpec((1, p_max, d), lambda b, i, pids: (pids[i], 0, 0)),
+        pl.BlockSpec((1, 1, p_max), lambda b, i, pids: (i, 0, 0)),
     ]
-    inputs = [q_i8.astype(jnp.int8),
-              alpha.reshape(2 * q_pad, 1).astype(jnp.float32),
-              beta.reshape(q_pad, 1).astype(jnp.float32),
+    inputs = [q_i8.astype(jnp.int8), alpha.astype(jnp.float32),
+              beta.astype(jnp.float32),
               lo.reshape(1, d).astype(jnp.float32),
               scale.reshape(1, d).astype(jnp.float32),
-              codes.astype(jnp.int8), valid.astype(jnp.int8),
-              ids.astype(jnp.int32), qsel.astype(jnp.int8)]
+              codes.astype(jnp.int8),
+              probe_ids(valid, ids, part_ids, attrs, attr_filter)]
+    if mqo:
+        in_specs.append(pl.BlockSpec((1, 1, 1, qt),
+                                     lambda b, i, pids: (i, b, 0, 0)))
+        inputs.append(qsel_rows(qsel, q_pad, qt))
     if has_norms:
-        in_specs.append(pl.BlockSpec((1, p_max), lambda i, pids: (pids[i], 0)))
-        inputs.append(norms.astype(jnp.float32))
-    if attr_filter is not None:
-        assert attrs is not None, "attr_filter needs the attrs tensor"
-        n_attr = attrs.shape[-1]
-        in_specs.append(
-            pl.BlockSpec((1, p_max, n_attr), lambda i, pids: (pids[i], 0, 0)))
-        inputs.append(attrs.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec((1, 1, p_max),
+                                     lambda b, i, pids: (i, 0, 0)))
+        inputs.append(norms[part_ids].astype(jnp.float32)[:, None, :])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n,),
+        grid=(q_pad // qt, n),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((q_pad, k_out), lambda i, pids: (0, 0)),
-            pl.BlockSpec((q_pad, k_out), lambda i, pids: (0, 0)),
+            pl.BlockSpec((qt, k_out), lambda b, i, pids: (b, 0)),
+            pl.BlockSpec((qt, k_out), lambda b, i, pids: (b, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((q_pad, k_out), jnp.float32),
-            pltpu.VMEM((q_pad, k_out), jnp.int32),
+            pltpu.VMEM((qt, k_out), jnp.float32),
+            pltpu.VMEM((qt, k_out), jnp.int32),
         ],
     )
     kernel = pl.pallas_call(
         functools.partial(_sq_scan_kernel, k_out=k_out, metric=metric,
-                          mqo=mqo, attr_filter=attr_filter,
-                          has_norms=has_norms),
+                          mqo=mqo, has_norms=has_norms),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((q_pad, k_out), jnp.float32),
@@ -229,7 +211,7 @@ def sq_scan_topk(
         ],
         interpret=interpret,
     )
-    out_s, out_i = kernel(part_ids.astype(jnp.int32), *inputs)
+    out_s, out_i = kernel(part_ids, *inputs)
     if q_pad != q_n:
         out_s, out_i = out_s[:q_n], out_i[:q_n]
     return out_s, out_i

@@ -119,8 +119,6 @@ class RooflineTerms:
 
 def extract(compiled, flops_correction: float = 0.0) -> RooflineTerms:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     return RooflineTerms(

@@ -403,7 +403,10 @@ class FrontDoor:
         try:
             with self._exec_guard(spec), obs_trace.activate(shared):
                 if len(reqs) == 1:
-                    results = [self.engine.query(reqs[0].vecs, spec)]
+                    # recorded at admission: the engine must not record
+                    # it a second time
+                    results = [self.engine.query_unrecorded(reqs[0].vecs,
+                                                            spec)]
                 else:
                     results = self.engine.query_batched(
                         [r.vecs for r in reqs], spec)

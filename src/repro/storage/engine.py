@@ -302,6 +302,7 @@ class MicroNN:
             parts[live].astype(np.int64), len(cents),
             pad_to=effective_pad_to(self.config), codes=codes_live)
         vec, vid, vat, val, counts, cod = packed
+        code_tier = None if cod is None else jnp.asarray(cod)
         idx = IVFIndex(
             centroids=jnp.asarray(cents), csizes=jnp.asarray(csizes),
             vectors=jnp.asarray(vec), ids=jnp.asarray(vid),
@@ -311,10 +312,10 @@ class MicroNN:
                                    attrs.shape[1],
                                    quantized=cod is not None),
             base_mean_size=jnp.asarray(max(counts.mean(), 1.0), jnp.float32),
-            codes=None if cod is None else jnp.asarray(cod),
+            codes=code_tier,
             qstats=qstats,
             code_norms=None if cod is None else quantize.row_norms(
-                qstats, jnp.asarray(cod)),
+                qstats, code_tier),
             drift=jnp.zeros((len(cents),), jnp.float32),
             config=self.config)
         # restore the monitor's maintenance signals (drift accumulators +
@@ -820,7 +821,19 @@ class MicroNN:
         # global load + branch (plus the fleet-mode SLO histogram
         # check), preserving the <=3% off-path gate in bench_obs
         rec = obs_recorder._ACTIVE
-        if rec is None and self._h_query_s is None:
+        res = self.query_unrecorded(queries, spec, trace=trace)
+        if rec is not None:
+            rec.record(obs_recorder.SITE_ENGINE, self.tenant, queries,
+                       spec, result=res)
+        return res
+
+    def query_unrecorded(self, queries: np.ndarray,
+                         spec: Optional[QuerySpec] = None, *,
+                         trace: bool = False) -> ResultSet:
+        """`query()` without the flight-recorder hook: for callers that
+        captured the request themselves (the front door records at
+        admission, so its solo dispatch must not record it again)."""
+        if self._h_query_s is None:
             if not (trace and obs_trace.enabled()):
                 return self._query_inner(queries, spec)
             return self._query_traced(queries, spec)
@@ -829,11 +842,7 @@ class MicroNN:
             res = self._query_inner(queries, spec)
         else:
             res = self._query_traced(queries, spec)
-        if self._h_query_s is not None:
-            self._h_query_s.observe(time.perf_counter() - t0)
-        if rec is not None:
-            rec.record(obs_recorder.SITE_ENGINE, self.tenant, queries,
-                       spec, result=res)
+        self._h_query_s.observe(time.perf_counter() - t0)
         return res
 
     def _query_traced(self, queries: np.ndarray,

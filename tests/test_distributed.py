@@ -28,11 +28,8 @@ centers = rng.normal(size=(16, 32)) * 5
 X = (centers[rng.integers(0, 16, 2048)] + rng.normal(size=(2048, 32))).astype(np.float32)
 cfg = IVFConfig(dim=32, target_partition_size=64, kmeans_iters=40, delta_capacity=128)
 idx = ivf.build_index(X, cfg=cfg)
-try:    # jax >= 0.5 wants explicit axis types; 0.4.x has neither kwarg
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-except (AttributeError, TypeError):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 Q = jnp.asarray(X[:8] + 0.05 * rng.normal(size=(8, 32)).astype(np.float32))
 ref = search.ann_search(idx, Q, 10, n_probe=6)
 for merge in ("tournament", "allgather"):
@@ -56,8 +53,6 @@ lw = steps.train_lowerable(arch, shape, mesh, scan=False)
 lowered = steps.lower(lw, mesh)
 compiled = lowered.compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):   # jax 0.4.x returns [dict]
-    ca = ca[0]
 out["train_flops"] = ca["flops"]
 
 # run it with real (randomly initialised) values

@@ -189,6 +189,24 @@ def test_frontdoor_capture_replays(tmp_path):
     eng.store.close()
 
 
+def test_frontdoor_solo_dispatch_records_once(tmp_path):
+    # one blocking caller: every window holds a single request, which the
+    # dispatcher runs through the engine's solo path -- captured at
+    # admission only, never a second time at the engine site
+    from repro.serving import FrontDoor
+    eng, X = _mk(tmp_path, "fd1")
+    cap = str(tmp_path / "cap.db")
+    spec = Q.knn(k=5, n_probe=4)
+    with FrontDoor(eng) as fd, obs_recorder.recording(cap) as rec:
+        for i in range(4):
+            fd.query(X[i:i + 1], spec, timeout=30)
+        assert rec.recorded == 4
+    assert fd.stats()["batches"] == 0
+    recs = obs_recorder.load(cap)
+    assert [r.site for r in recs] == [obs_recorder.SITE_FRONTDOOR] * 4
+    eng.store.close()
+
+
 # -- noisy-neighbor attribution ----------------------------------------------
 
 

@@ -76,9 +76,17 @@ def test_fold_queries_two_term_shapes_and_precision():
 @pytest.mark.parametrize("metric", ["l2", "ip"])
 @pytest.mark.parametrize("with_norms", [True, False])
 def test_int8_scan_xla_matches_pallas_interpret_bitwise(metric, with_norms):
+    _int8_parity_case(metric, with_norms, q_n=5)
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_int8_scan_parity_across_query_tiles(metric):
+    _int8_parity_case(metric, True, q_n=40)     # two 32-row query tiles
+
+
+def _int8_parity_case(metric, with_norms, q_n):
     idx, X = _mk_index(metric=metric)
-    rng = np.random.default_rng(7)
-    q = jnp.asarray(X[:5])
+    q = jnp.asarray(X[:q_n])
     plan = executor.plan_ann(idx, q, k=16, n_probe=4)
     norms = idx.code_norms if with_norms else None
     kprime = 48

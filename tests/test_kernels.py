@@ -45,9 +45,9 @@ def test_scan_topk_dtypes(dtype):
                                rtol=tol, atol=tol)
 
 
-def test_scan_topk_mqo_mask():
+def _mqo_case(Q):
     rng = np.random.default_rng(5)
-    k, p_max, dim, Q, n, K = 8, 16, 32, 6, 5, 6
+    k, p_max, dim, n, K = 8, 16, 32, 5, 6
     vectors = jnp.asarray(rng.normal(size=(k, p_max, dim)).astype(np.float32))
     valid = jnp.asarray(rng.random((k, p_max)) > 0.1)
     ids = jnp.arange(k * p_max, dtype=jnp.int32).reshape(k, p_max)
@@ -59,6 +59,14 @@ def test_scan_topk_mqo_mask():
     s_r, i_r = ref.ivf_scan_ref(queries, vectors, valid, ids, part_ids, K,
                                 qsel=qsel)
     assert (np.asarray(i_k) == np.asarray(i_r)).all()
+
+
+def test_scan_topk_mqo_mask():
+    _mqo_case(6)
+
+
+def test_scan_topk_mqo_mask_query_tiles():
+    _mqo_case(70)       # three 32-row query tiles, the last one padded
 
 
 @pytest.mark.parametrize("k_cent,tile", [(100, 32), (256, 128), (300, 256)])

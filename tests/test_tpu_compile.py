@@ -1,0 +1,96 @@
+"""Ahead-of-time compiles of the scan kernels for a described TPU v5e.
+
+No chip is needed: the TPU compiler compiles for a topology that is
+described, not attached, and refuses what the chip would refuse (block
+tiling, VMEM over the scoped limit, ops Mosaic cannot lower). Interpret
+mode cannot show any of that. The shapes are the widths the deployments
+use: SIFT (d=128) and GIST (d=960), p_max as the SIFT-1M build gives it.
+
+The topology is described inside a fixture, never at import, so every
+test worker collects the same tests and only the one running this file
+loads the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.hybrid import And, Pred, compile_filter
+from repro.kernels import ivf_scan, sq_scan
+
+# p_max of the SIFT-1M build (1,000,000 x 128, target partition size 100,
+# int8 padding to 32) as chip_smoke.py printed it on a v5e
+P_MAX = 576
+K_PARTS = 10_000
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("d,q_n,k_parts,mqo,filtered", [
+    (128, 1, K_PARTS, False, False),
+    (128, 32, K_PARTS, True, False),
+    (128, 32, K_PARTS, True, True),
+    (960, 32, 1000, True, False),
+], ids=["d128-q1", "d128-q32-mqo", "d128-q32-filter", "d960-q32-mqo"])
+def test_ivf_scan_compiles_for_v5e(one_chip, d, q_n, k_parts, mqo,
+                                   filtered):
+    n_probe, k_out = 256, 100
+    pred = compile_filter(And((Pred(0, "==", 3.0), Pred(1, ">=", 2020.0)))) \
+        if filtered else None
+    shapes = [((q_n, d), jnp.float32), ((k_parts, P_MAX, d), jnp.float32),
+              ((k_parts, P_MAX), jnp.bool_), ((k_parts, P_MAX), jnp.int32),
+              ((n_probe,), jnp.int32), ((q_n, n_probe), jnp.bool_),
+              ((k_parts, P_MAX, 2), jnp.float32)]
+
+    def fn(q, v, valid, ids, pids, qsel, attrs):
+        return ivf_scan.ivf_scan_topk(
+            q, v, valid, ids, pids, k_out, qsel=qsel if mqo else None,
+            attrs=attrs, attr_filter=pred, interpret=False)
+    _compile(fn, shapes, one_chip)
+
+
+@pytest.mark.parametrize("d,q_n,k_parts,with_norms", [
+    (128, 32, K_PARTS, True),
+    (128, 1, K_PARTS, False),
+    (960, 32, 1000, True),
+], ids=["d128-q32-norms", "d128-q1-decode", "d960-q32-norms"])
+def test_sq_scan_compiles_for_v5e(one_chip, d, q_n, k_parts, with_norms):
+    n_probe, k_out = 256, 400
+    shapes = [((q_n, d), jnp.float32), ((k_parts, P_MAX, d), jnp.int8),
+              ((d,), jnp.float32), ((d,), jnp.float32),
+              ((k_parts, P_MAX), jnp.bool_), ((k_parts, P_MAX), jnp.int32),
+              ((n_probe,), jnp.int32), ((q_n, n_probe), jnp.bool_),
+              ((k_parts, P_MAX), jnp.float32)]
+
+    def fn(q, codes, lo, scale, valid, ids, pids, qsel, norms):
+        return sq_scan.sq_scan_topk(
+            q, codes, lo, scale, valid, ids, pids, k_out, qsel=qsel,
+            norms=norms if with_norms else None, interpret=False)
+    _compile(fn, shapes, one_chip)
