@@ -59,7 +59,6 @@ number of live entries -- both surface through MicroNN.stats().
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Callable, Optional, Tuple
 
@@ -707,45 +706,34 @@ def _bucket(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
-def _record_resident_probe(tr, index, q: jax.Array, spec: QuerySpec):
-    """Probe span for a traced resident query. The real probe runs fused
-    inside the jitted entry point, so tracing re-derives it eagerly from
-    the same centroids (identical math -- find_nearest_centroids is what
-    both plan variants call); the duplicate work only happens on
-    explicitly traced queries."""
-    kp = index.centroids.shape[0]
-    if spec.kind == "exact":
-        tr.record(obs_trace.STAGE_PROBE, 0.0, partitions=int(kp),
-                  n_probe=int(kp), kind="exact")
-        return
-    if spec.predicate is not None and spec.hybrid == "pre":
-        tr.record(obs_trace.STAGE_PROBE, 0.0, partitions=0,
-                  rows_cap=int(spec.cap or 0), kind="prefilter")
-        return
-    t0 = time.perf_counter()
-    qn = normalize_if_cosine(q.astype(jnp.float32), index.config.metric)
-    parts = np.unique(np.asarray(
-        find_nearest_centroids(index, qn, spec.n_probe)))
-    tr.record(obs_trace.STAGE_PROBE, (time.perf_counter() - t0) * 1e3,
-              partitions=int(parts.size), n_probe=int(min(spec.n_probe, kp)),
-              kind="ann")
-
-
-def _record_resident_scan(tr, index, spec: QuerySpec, b: int,
-                          dt_ms: float, compiled: int):
-    """Scan/rerank/merge spans for a traced resident query: one fused
-    jitted call covers all three stages, so rerank and merge are recorded
-    as fused markers (dur folded into the scan span)."""
+def _record_resident_spans(tr, index, spec: QuerySpec, Q: int, b: int,
+                           compiled: int):
+    """Probe/scan/rerank/merge spans for a traced resident query. The one
+    jitted call does all four stages on the device, so each is a fused
+    marker: counters only, no time (that is in `dispatch` and
+    `device_wait`) and no device work of its own. The probe's
+    `partitions` is the union bound min(Q*n_probe, k), capped by the
+    spec's union cap: exact for Q=1, an upper bound for a batch."""
     kp, p_max, _ = index.vectors.shape
+    if spec.kind == "exact":
+        n_parts, probe = kp, {"n_probe": kp, "kind": "exact"}
+    elif spec.predicate is not None and spec.hybrid == "pre":
+        n_parts = 0
+        probe = {"rows_cap": int(spec.cap or 0), "kind": "prefilter"}
+    else:
+        n_probe = min(spec.n_probe, kp)
+        n_parts = min(Q * n_probe, kp,
+                      kp if spec.u_max is None else spec.u_max)
+        probe = {"n_probe": n_probe, "kind": "ann"}
+    tr.record(obs_trace.STAGE_PROBE, 0.0, partitions=n_parts, fused=1,
+              **probe)
     backend = spec.on_backend or default_backend()
     quantized = spec.use_quantized
     if quantized is None:
         quantized = index.codes is not None
     use_sq = bool(quantized) and spec.kind == "ann" and \
         spec.hybrid != "pre"
-    n_parts = tr.counter(obs_trace.STAGE_PROBE, "partitions",
-                         default=int(kp))
-    tr.record(obs_trace.STAGE_SCAN, dt_ms,
+    tr.record(obs_trace.STAGE_SCAN, 0.0,
               partitions=n_parts, rows=n_parts * p_max, chunks=1,
               backend=backend, q_bucket=b, quantized=use_sq,
               compiled=compiled, cache_hit=(compiled == 0), fused=1)
@@ -770,6 +758,10 @@ def run(index, queries: jax.Array, spec: QuerySpec, *,
     the key (the index pytree structure -- codes present or not -- is
     itself part of jit's implicit key). Paged execution streams the
     probe set through the frame pool (paged_search).
+
+    A traced resident run does exactly the device work of an untraced
+    one: the host stages `stage_in` and `dispatch` are timed around the
+    same calls, and the fused stages get counter-only spans.
     """
     if isinstance(index, PagedIndex):
         if spec.predicate is not None and spec.hybrid == "pre":
@@ -789,24 +781,20 @@ def run(index, queries: jax.Array, spec: QuerySpec, *,
             n_probe=spec.n_probe, attr_filter=_spec_filter(spec),
             backend=spec.on_backend, quantized=spec.use_quantized,
             spec=spec)
-    q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
-    Q = q.shape[0]
-    b = _bucket(Q) if bucket else Q
-    if b != Q:
-        q = jnp.concatenate([q, jnp.zeros((b - Q, q.shape[1]), q.dtype)])
-    qmask = jnp.arange(b) < Q
     tr = obs_trace.current()
-    if tr is None:
+    with obs_trace.stage(obs_trace.STAGE_STAGE_IN, tr):
+        q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
+        Q = q.shape[0]
+        b = _bucket(Q) if bucket else Q
+        if b != Q:
+            q = jnp.concatenate([q, jnp.zeros((b - Q, q.shape[1]),
+                                              q.dtype)])
+        qmask = jnp.arange(b) < Q
+    tc0 = _TRACE_COUNT
+    with obs_trace.stage(obs_trace.STAGE_DISPATCH, tr):
         res = _run_spec(index, q, qmask, spec)
-    else:
-        _record_resident_probe(tr, index, q[:Q], spec)
-        tc0 = _TRACE_COUNT
-        t0 = time.perf_counter()
-        res = _run_spec(index, q, qmask, spec)
-        jax.block_until_ready(res.scores)
-        _record_resident_scan(tr, index, spec, b,
-                              (time.perf_counter() - t0) * 1e3,
-                              _TRACE_COUNT - tc0)
+    if tr is not None:
+        _record_resident_spans(tr, index, spec, Q, b, _TRACE_COUNT - tc0)
     if b != Q:
         res = SearchResult(ids=res.ids[:Q], scores=res.scores[:Q])
     return ResultSet.of(res, spec)
@@ -907,30 +895,28 @@ def _rerank_from_store(store, q: jax.Array, cand_ids: jax.Array,
     by them. Disk-gather cost is O(unique candidates), independent of the
     scan width, which is the point of scanning codes."""
     tr = obs_trace.current()
-    t0 = time.perf_counter() if tr is not None else 0.0
-    cand = np.asarray(cand_ids)
-    got = cand != INVALID_ID
-    Q, kc = cand.shape
-    d = store.dim
-    v = np.zeros((Q, kc, d), np.float32)
-    n_uniq = 0
-    if got.any():
-        uniq = np.unique(cand[got])
-        n_uniq = int(uniq.size)
-        rows, found = store.vectors_for(uniq)
-        rows = np.asarray(normalize_if_cosine(
-            jnp.asarray(rows, jnp.float32), metric))
-        idx = np.searchsorted(uniq, np.where(got, cand, uniq[0]))
-        idx = np.clip(idx, 0, len(uniq) - 1)
-        got = got & (uniq[idx] == cand) & found[idx]
-        v[got] = rows[idx[got]]
-    out = _paged_rerank(q, jnp.asarray(v), jnp.asarray(got),
-                        jnp.asarray(cand), k_out=k_out, metric=metric)
-    if tr is not None:
-        jax.block_until_ready(out[0])
-        tr.record(obs_trace.STAGE_RERANK,
-                  (time.perf_counter() - t0) * 1e3,
-                  candidates=Q * kc, rows_gathered=n_uniq, k_out=k_out)
+    with obs_trace.stage(obs_trace.STAGE_RERANK, tr) as st:
+        cand = np.asarray(cand_ids)
+        got = cand != INVALID_ID
+        Q, kc = cand.shape
+        d = store.dim
+        v = np.zeros((Q, kc, d), np.float32)
+        n_uniq = 0
+        if got.any():
+            uniq = np.unique(cand[got])
+            n_uniq = int(uniq.size)
+            rows, found = store.vectors_for(uniq)
+            rows = np.asarray(normalize_if_cosine(
+                jnp.asarray(rows, jnp.float32), metric))
+            idx = np.searchsorted(uniq, np.where(got, cand, uniq[0]))
+            idx = np.clip(idx, 0, len(uniq) - 1)
+            got = got & (uniq[idx] == cand) & found[idx]
+            v[got] = rows[idx[got]]
+        out = _paged_rerank(q, jnp.asarray(v), jnp.asarray(got),
+                            jnp.asarray(cand), k_out=k_out, metric=metric)
+        if tr is not None:
+            jax.block_until_ready(out[0])
+            st.set(candidates=Q * kc, rows_gathered=n_uniq, k_out=k_out)
     return out
 
 
@@ -1042,20 +1028,17 @@ def paged_search(
             f"({pindex.cache.payload}); cannot force quantized={quantized}"
 
     tr = obs_trace.current()
-    t_probe = time.perf_counter() if tr is not None else 0.0
-    if kind == "exact":
-        counts = np.asarray(pindex.counts)
-        upart = np.nonzero(counts > 0)[0]
-        qsel = jnp.broadcast_to(qmask[:, None], (b, len(upart)))
-    else:
-        assert kind == "ann", kind
-        upart, qsel = _paged_probes(pindex, q, n_probe, qmask=qmask)
-
-    n = len(upart)
-    if tr is not None:
-        tr.record(obs_trace.STAGE_PROBE,
-                  (time.perf_counter() - t_probe) * 1e3,
-                  partitions=int(n), n_probe=int(n_probe), kind=kind)
+    with obs_trace.stage(obs_trace.STAGE_PROBE, tr) as st:
+        if kind == "exact":
+            counts = np.asarray(pindex.counts)
+            upart = np.nonzero(counts > 0)[0]
+            qsel = jnp.broadcast_to(qmask[:, None], (b, len(upart)))
+        else:
+            assert kind == "ann", kind
+            upart, qsel = _paged_probes(pindex, q, n_probe, qmask=qmask)
+        n = len(upart)
+        if tr is not None:
+            st.set(partitions=int(n), n_probe=int(n_probe), kind=kind)
     p_max = cache.p_max
     if use_sq:
         k_run = min(max(k, k * cfg.rerank_factor), max(n * p_max, 1))
@@ -1113,27 +1096,25 @@ def paged_search(
                 fidx = jnp.asarray(frames.astype(np.int32))
                 cq = qsel[:, s:s + chunk]
                 k_chunk = min(k_run, len(cpids) * p_max)
-                t_scan = time.perf_counter() if tr is not None else 0.0
-                if use_sq:
-                    cs, ci = _scan_frames_sq(
-                        q, cache.payload_pool, pindex.qstats,
-                        cache.valid_pool, cache.ids_pool, fidx, cq,
-                        attrs_pool, k_out=k_chunk, metric=cfg.metric,
-                        backend=backend, attr_filter=attr_filter)
-                else:
-                    cs, ci = _scan_frames(
-                        q, cache.payload_pool, cache.valid_pool,
-                        cache.ids_pool, fidx, cq, attrs_pool,
-                        k_out=k_chunk, metric=cfg.metric, backend=backend,
-                        attr_filter=attr_filter)
-                if tr is not None:
-                    jax.block_until_ready(cs)
-                    tr.record(obs_trace.STAGE_SCAN,
-                              (time.perf_counter() - t_scan) * 1e3,
-                              chunks=1, partitions=len(cpids),
-                              rows=len(cpids) * p_max,
-                              backend=backend or default_backend(),
-                              quantized=use_sq, q_bucket=b)
+                with obs_trace.stage(obs_trace.STAGE_SCAN, tr) as st:
+                    if use_sq:
+                        cs, ci = _scan_frames_sq(
+                            q, cache.payload_pool, pindex.qstats,
+                            cache.valid_pool, cache.ids_pool, fidx, cq,
+                            attrs_pool, k_out=k_chunk, metric=cfg.metric,
+                            backend=backend, attr_filter=attr_filter)
+                    else:
+                        cs, ci = _scan_frames(
+                            q, cache.payload_pool, cache.valid_pool,
+                            cache.ids_pool, fidx, cq, attrs_pool,
+                            k_out=k_chunk, metric=cfg.metric,
+                            backend=backend, attr_filter=attr_filter)
+                    if tr is not None:
+                        jax.block_until_ready(cs)
+                        st.set(chunks=1, partitions=len(cpids),
+                               rows=len(cpids) * p_max,
+                               backend=backend or default_backend(),
+                               quantized=use_sq, q_bucket=b)
             finally:
                 cache.unpin(frames)
             run_s, run_i = merge_topk(run_s, run_i, cs, ci, k_run)
@@ -1157,15 +1138,13 @@ def paged_search(
         s_m, i_m = (run_s, run_i) if n else (
             jnp.zeros((b, 0), jnp.float32), jnp.zeros((b, 0), jnp.int32))
 
-    t_merge = time.perf_counter() if tr is not None else 0.0
-    s_f, i_f = _paged_epilogue(q, s_m, i_m, pindex.delta, qmask,
-                               k=k, k_scan=k_scan, metric=cfg.metric,
-                               attr_filter=attr_filter)
-    if tr is not None:
-        jax.block_until_ready(s_f)
-        tr.record(obs_trace.STAGE_MERGE,
-                  (time.perf_counter() - t_merge) * 1e3,
-                  k=int(k), k_scan=int(k_scan), fused=0)
+    with obs_trace.stage(obs_trace.STAGE_MERGE, tr) as st:
+        s_f, i_f = _paged_epilogue(q, s_m, i_m, pindex.delta, qmask,
+                                   k=k, k_scan=k_scan, metric=cfg.metric,
+                                   attr_filter=attr_filter)
+        if tr is not None:
+            jax.block_until_ready(s_f)
+            st.set(k=int(k), k_scan=int(k_scan), fused=0)
     if b != Q:
         s_f, i_f = s_f[:Q], i_f[:Q]
     return ResultSet(ids=i_f, scores=s_f, spec=spec)

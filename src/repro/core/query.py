@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace as obs_trace
 from .hybrid import Node
 from .topk import dedup_by_id, merge_topk
 from .types import INVALID_ID, SearchResult
@@ -254,8 +255,16 @@ class ResultSet:
             attrs=None if self.attrs is None else self.attrs[qi][got])
 
     def to_numpy(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Host copies of ids and scores, made once: the `device_wait`
+        stage (block until both are ready), then the `fetch` stage (the
+        device->host copies). Both record into `self.trace` -- the
+        query's own activation scope has closed by now -- and into the
+        profiler when it collects."""
         if self._np is None:
-            self._np = (np.asarray(self.ids), np.asarray(self.scores))
+            with obs_trace.stage(obs_trace.STAGE_DEVICE_WAIT, self.trace):
+                jax.block_until_ready((self.ids, self.scores))
+            with obs_trace.stage(obs_trace.STAGE_FETCH, self.trace):
+                self._np = (np.asarray(self.ids), np.asarray(self.scores))
         return self._np
 
     def split(self, sizes: Sequence[int]) -> List["ResultSet"]:

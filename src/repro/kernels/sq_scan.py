@@ -210,6 +210,7 @@ def sq_scan_topk(
             jax.ShapeDtypeStruct((q_pad, k_out), jnp.int32),
         ],
         interpret=interpret,
+        name="sq_scan_topk",
     )
     out_s, out_i = kernel(part_ids, *inputs)
     if q_pad != q_n:

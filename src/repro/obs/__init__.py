@@ -6,8 +6,9 @@ Two pieces, one import surface:
     registry; every subsystem (pager, executor, front door, scheduler,
     engine) registers into `default_registry()` under labeled scopes so
     `MicroNN.stats()` is a derived view of a single source of truth.
-  * `trace` -- thread-local per-query spans (`QueryTrace`), the bounded
-    `TraceRing` of recent traces + maintenance events, and the
+  * `trace` -- the host-stage hook `stage()` (per-query `QueryTrace`
+    spans, and `micronn.*` events when a JAX profiler collects), the
+    bounded `TraceRing` of recent traces + maintenance events, and the
     slow-query log.
   * `recorder` -- the workload flight recorder (PR 10): bounded,
     sampled on-disk capture of (ts_offset, tenant, spec, vectors) and

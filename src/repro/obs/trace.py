@@ -2,31 +2,49 @@
 
 `MicroNN.query(vecs, spec, trace=True)` (or `MicroNN.explain(vecs,
 spec)`) activates a thread-local QueryTrace for the duration of that one
-query; every layer the query flows through -- engine planner, executor
-probe/scan/rerank/merge, pager fault path -- checks `trace.current()`
-and, when a trace is active, records a named Span carrying wall time and
-work counters:
+query. Every layer the query flows through marks its host stages with
+ONE hook, `stage(name, trace)`, which writes to two sinks:
 
+  * the QueryTrace, when one is active: a named Span carrying wall time
+    (`time.perf_counter`) and work counters;
+  * the JAX profiler, when a profiler session is collecting
+    (`jax.profiler.TraceAnnotation.is_enabled()`): a host event named
+    `micronn.<stage>` on the profiler's own clock, so a device-idle gap
+    of a profiled window can be put down to the stage the host was in.
+    This sink needs no QueryTrace: an untraced query under the profiler
+    still emits its stages.
+
+Stages, in the order a resident query crosses them:
+
+    stage_in      host work before the jitted call, less `plan`: the
+                  query's host->device copy and the query counter
+                  (engine), bucket padding and the query mask (executor);
+                  two recordings, calls=2
     plan          spec resolution (hybrid pre/post choice), kind, k
-    probe         centroid probe: partitions in the probe union, n_probe
+    dispatch      the jitted `_run_spec` call, until it returns
+    device_wait   ResultSet.to_numpy(): block until ids + scores are ready
+    fetch         ResultSet.to_numpy(): the device->host copies after it
+    probe / scan  resident: counters only (fused=1, no time and no device
+    rerank/merge  work of their own -- the one jitted call does all four;
+                  its time is in dispatch + device_wait). probe carries
+                  n_probe and `partitions`, the probe-union bound
+                  min(Q*n_probe, k); scan the compile count (cache hit
+                  <=> compiled=0), backend, Q-bucket and rows
     pager_fault   paged only: frames hit/missed/staged-consumed, bytes
                   read from SQLite, accumulated over every chunk fault
-    scan          the fused scan: partitions, rows, chunks, backend,
-                  Q-bucket, jit compile count (cache hit <=> compiled=0)
-    rerank        quantized only: candidates, rows gathered (fused=1 on
-                  the resident path, where rerank lives inside the one
-                  jitted call)
-    merge         delta-merge epilogue (fused=1 resident)
+    probe / scan  paged: timed per stage (the host drives each chunk;
+    rerank/merge  a traced paged query blocks at each stage end)
     queue_wait /  front-door requests only: admission latency and the
     split         coalesced-batch sub-span (callers, batch rows)
 
-Tracing-off cost: `current()` is one module-bool test plus one
-thread-local dict lookup (~100 ns); NO span objects, dicts, or registry
-entries are allocated when no trace is active -- pinned by the bench_obs
-overhead gate (<= 3% on a ~150 us query) and the zero-allocation test.
-`set_enabled(False)` is the global kill-switch that makes every hook a
-no-op even under an activated trace; it doubles as the baseline arm of
-the overhead benchmark.
+Off-path cost: with no active trace and no profiler collecting, a hook
+is one module-bool test plus the profiler check (~0.1 us together) and
+returns the shared no-op `OFF` stage: NO span objects, dicts, or registry
+entries are allocated -- pinned by the bench_obs overhead gate (<= 3% on
+a ~150 us query) and the zero-allocation test. `set_enabled(False)` is
+the global kill-switch that makes every hook a no-op for both sinks,
+even under an activated trace or a profiler session; it doubles as the
+baseline arm of the overhead benchmark.
 
 The engine owns a TraceRing: a bounded ring of the last N QueryTraces
 plus the maintenance event log -- structured MaintEvents the scheduler
@@ -43,8 +61,14 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 # -- canonical stage names (tests assert against these) ---------------------
+STAGE_STAGE_IN = "stage_in"
 STAGE_PLAN = "plan"
+STAGE_DISPATCH = "dispatch"
+STAGE_DEVICE_WAIT = "device_wait"
+STAGE_FETCH = "fetch"
 STAGE_PROBE = "probe"
 STAGE_FAULT = "pager_fault"
 STAGE_SCAN = "scan"
@@ -52,6 +76,8 @@ STAGE_RERANK = "rerank"
 STAGE_MERGE = "merge"
 STAGE_QUEUE = "queue_wait"
 STAGE_SPLIT = "split"
+# profiler-side name of a stage: PROFILER_PREFIX + stage
+PROFILER_PREFIX = "micronn."
 
 # global kill-switch: False turns every hook into a no-op regardless of
 # activated traces (the overhead benchmark's baseline arm)
@@ -87,6 +113,68 @@ def activate(trace: "QueryTrace"):
         yield trace
     finally:
         d["active"] = prev
+
+
+class _Stage:
+    """One open host stage, writing to whichever sinks were on when the
+    hook was called: `trace` (a QueryTrace or None) and the profiler."""
+
+    __slots__ = ("name", "trace", "counters", "_ann", "_t0")
+
+    def __init__(self, name: str, trace: Optional["QueryTrace"]):
+        self.name = name
+        self.trace = trace
+        self.counters: Dict[str, object] = {}
+        self._ann = None
+        self._t0 = 0.0
+
+    def set(self, **counters):
+        """Counters known only inside the stage (recorded at its end)."""
+        self.counters.update(counters)
+
+    def __enter__(self):
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(PROFILER_PREFIX + self.name)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        dt_ms = (time.perf_counter() - self._t0) * 1e3
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        if self.trace is not None:
+            self.trace.record(self.name, dt_ms, **self.counters)
+        return False
+
+
+class _Off:
+    """The shared no-op stage every hook returns on the off path."""
+
+    __slots__ = ()
+
+    def set(self, **counters):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, et, ev, tb):
+        return False
+
+
+OFF = _Off()
+
+
+def stage(name: str, trace: Optional["QueryTrace"] = None):
+    """THE host-stage hook: `with stage(STAGE_X, tr) as st: ...` records
+    a Span into `trace` (pass `current()`, or the trace a ResultSet
+    carries) and, while a profiler session collects, a `micronn.<name>`
+    host event. With neither sink on it returns the shared `OFF` stage
+    and allocates nothing."""
+    if _ENABLED and (trace is not None or TraceAnnotation.is_enabled()):
+        return _Stage(name, trace)
+    return OFF
 
 
 @dataclasses.dataclass
@@ -147,14 +235,6 @@ class QueryTrace:
             span = Span(name)
             self.spans[name] = span
         span.add(dur_ms, counters)
-
-    @contextlib.contextmanager
-    def span(self, name: str, **counters):
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            self.record(name, (time.perf_counter() - t0) * 1e3, **counters)
 
     def finish(self):
         self.total_ms = (time.perf_counter() - self._t0) * 1e3

@@ -795,13 +795,17 @@ class MicroNN:
         """THE query entry point: execute a declarative QuerySpec.
 
         `trace=True` activates a per-query QueryTrace for this call: every
-        layer the query crosses (planner, probe, pager, fused scan,
-        rerank, merge) records a stage span, the trace lands in the
-        engine's ring (`self.traces`, incl. the slow-query log) and rides
-        back on `result.trace`. With `trace=False` (default) no span is
-        allocated -- unless an OUTER trace is already active on this
-        thread (the front door's shared fused-call trace), in which case
-        the layers keep recording into that one.
+        stage the query crosses (stage-in, planner, dispatch, probe,
+        pager, fused scan, rerank, merge) records a span; the call then
+        waits for its result and copies it to the host (`device_wait`,
+        `fetch`), so the trace covers the whole query before it lands in
+        the engine's ring (`self.traces`, incl. the slow-query log) and
+        rides back on `result.trace`. With `trace=False` (default) no
+        span is allocated -- unless an OUTER trace is already active on
+        this thread (the front door's shared fused-call trace), in which
+        case the layers keep recording into that one. Under a collecting
+        JAX profiler every stage is also a `micronn.<stage>` host event,
+        traced or not (obs/trace.py).
 
         The spec alone routes execution -- resident fused scan, paged
         frame-pool streaming, or the hybrid pre/post-filter choice (the
@@ -851,9 +855,12 @@ class MicroNN:
             mode="paged" if self.paged else "resident")
         with obs_trace.activate(tr):
             res = self._query_inner(queries, spec)
+        res.trace = tr
+        # the result's own stages (device_wait, fetch) complete the
+        # trace; the caller's to_numpy() then reuses the host copy
+        res.to_numpy()
         tr.finish()
         tr.result = res
-        res.trace = tr
         self.traces.append(tr)
         return res
 
@@ -869,8 +876,9 @@ class MicroNN:
         idx, optimizer = self.index, self.optimizer
         assert idx is not None, "build() or recover() first"
         spec = QuerySpec() if spec is None else spec
-        q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
-        self._c_queries.inc()
+        with obs_trace.stage(obs_trace.STAGE_STAGE_IN, obs_trace.current()):
+            q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
+            self._c_queries.inc()
         spec = self._resolve_spec_traced(idx, optimizer, spec,
                                          int(q.shape[0]))
         res = executor.run(idx, q, spec)
@@ -903,21 +911,19 @@ class MicroNN:
 
     def _resolve_spec_traced(self, idx, optimizer, spec: QuerySpec,
                              n_queries: int) -> QuerySpec:
-        """Spec resolution with the trace's `plan` span: records the
-        hybrid pre/post decision and the resolved shape when a trace is
-        active (no-op otherwise -- one thread-local lookup)."""
+        """Spec resolution as the `plan` stage: records the hybrid
+        pre/post decision and the resolved shape when a trace is active
+        (the profiler sink alone takes no counters)."""
         tr = obs_trace.current()
-        if tr is None:
-            return self._resolve_spec(idx, optimizer, spec)
-        t0 = time.perf_counter()
-        spec = self._resolve_spec(idx, optimizer, spec)
-        tr.record(obs_trace.STAGE_PLAN,
-                  (time.perf_counter() - t0) * 1e3,
-                  kind=spec.kind, k=int(spec.k),
-                  n_probe=int(spec.n_probe), hybrid=spec.hybrid,
-                  predicate=spec.predicate is not None)
-        tr.spec = spec
-        tr.n_queries += n_queries
+        with obs_trace.stage(obs_trace.STAGE_PLAN, tr) as st:
+            spec = self._resolve_spec(idx, optimizer, spec)
+            if tr is not None:
+                st.set(kind=spec.kind, k=int(spec.k),
+                       n_probe=int(spec.n_probe), hybrid=spec.hybrid,
+                       predicate=spec.predicate is not None)
+        if tr is not None:
+            tr.spec = spec
+            tr.n_queries += n_queries
         return spec
 
     def _resolve_spec(self, idx, optimizer, spec: QuerySpec) -> QuerySpec:

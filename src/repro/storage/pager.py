@@ -94,7 +94,6 @@ the classic double-buffer cost, bounded by scan_frames * frame_bytes.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
@@ -347,16 +346,14 @@ class PartitionCache:
         hits do not touch reference bits -- so the stream cannot evict or
         artificially refresh the hot working set."""
         tr = obs_trace.current()
-        if tr is None:
-            return self._pool.fault(self._tid, pids, admit)
-        t0 = time.perf_counter()
-        with self._pool._lock:
-            frames = self._pool.fault(self._tid, pids, admit)
-            h, m, st, nb = self._last_fault
-        tr.record(obs_trace.STAGE_FAULT,
-                  (time.perf_counter() - t0) * 1e3,
-                  hits=h, misses=m, staged=st, bytes_read=nb,
-                  admitted=bool(admit))
+        with obs_trace.stage(obs_trace.STAGE_FAULT, tr) as st:
+            if tr is None:
+                return self._pool.fault(self._tid, pids, admit)
+            with self._pool._lock:
+                frames = self._pool.fault(self._tid, pids, admit)
+                h, m, staged, nb = self._last_fault
+            st.set(hits=h, misses=m, staged=staged, bytes_read=nb,
+                   admitted=bool(admit))
         return frames
 
     def unpin(self, frames: np.ndarray):

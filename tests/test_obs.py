@@ -195,15 +195,27 @@ def test_trace_compile_counter_reconciles_resident(tmp_path):
 # -- tracing-off hot path: zero cost, zero allocation ------------------------
 
 
-def test_untraced_queries_allocate_nothing(tmp_path):
+def test_untraced_queries_allocate_nothing(tmp_path, monkeypatch):
     eng, X = _mk(tmp_path, "zero")
+    paged, _ = _mk(tmp_path, "zero-paged", paged=True, quant=True)
     spec = Q.knn(k=5, n_probe=4)
-    eng.query(X[:1], spec)                      # register + compile once
+    for e in (eng, paged):
+        e.query(X[:1], spec).to_numpy()         # register + compile once
     reg = obs_metrics.default_registry()
     size0, ring0 = reg.size(), len(eng.traces)
+    # with no trace and no profiler, every stage hook returns the shared
+    # no-op stage: building a stage object here fails the test
+    assert obs_trace.stage(obs_trace.STAGE_DISPATCH) is obs_trace.OFF
+
+    def no_stage(*a, **kw):
+        raise AssertionError("a stage was built with both sinks off")
+
+    monkeypatch.setattr(obs_trace, "_Stage", no_stage)
     for i in range(5):
-        rs = eng.query(X[i:i + 1], spec)
-        assert rs.trace is None
+        for e in (eng, paged):
+            rs = e.query(X[i:i + 1], spec)
+            assert rs.trace is None
+            rs.to_numpy()                       # device_wait + fetch hooks
     assert reg.size() == size0, "untraced query registered a new series"
     assert len(eng.traces) == ring0, "untraced query entered the ring"
     # global kill-switch: even trace=True records nothing
@@ -211,8 +223,86 @@ def test_untraced_queries_allocate_nothing(tmp_path):
     try:
         rs = eng.query(X[:1], spec, trace=True)
         assert rs.trace is None and len(eng.traces) == ring0
+        assert obs_trace.stage(obs_trace.STAGE_PLAN,
+                               obs_trace.QueryTrace()) is obs_trace.OFF
     finally:
         obs_trace.set_enabled(True)
+    eng.store.close()
+    paged.store.close()
+
+
+# -- host stages: both sinks, and no device work of their own ----------------
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_traced_resident_query_does_no_device_work_of_its_own(
+        tmp_path, monkeypatch, backend):
+    """A span-traced resident query records the host stages, runs no
+    probe of its own once the jitted call is compiled, and answers as an
+    untraced one does."""
+    eng, X = _mk(tmp_path, f"stages-{backend}", quant=True)
+    spec = Q.knn(k=5, n_probe=4).backend(backend)
+    q = X[7:8] + 0.01
+    eng.query(X[:1], spec).to_numpy()           # compile the one bucket
+
+    def no_probe(*a, **kw):
+        raise AssertionError("the probe ran outside the jitted call")
+
+    monkeypatch.setattr(executor, "find_nearest_centroids", no_probe)
+    monkeypatch.setattr(executor, "_probe_union", no_probe)
+    c0 = executor.trace_count()
+    traced = eng.query(q, spec, trace=True)
+    plain = eng.query(q, spec)
+    assert executor.trace_count() == c0
+    tr = traced.trace
+    for stage in ("plan", "stage_in", "dispatch", "device_wait", "fetch"):
+        assert stage in tr, (stage, tr.span_names)
+        assert tr.get(stage).dur_ms > 0, stage
+    assert tr.get("stage_in").calls == 2        # engine part + executor part
+    # the fused stages are counter-only markers
+    for stage in ("probe", "scan", "rerank", "merge"):
+        assert tr.get(stage).dur_ms == 0.0 and tr.counter(stage, "fused") == 1
+    assert tr.counter("probe", "partitions") == 4
+    assert tr.counter("scan", "compiled") == 0
+    assert tr.total_ms >= sum(tr.get(s).dur_ms for s in
+                              ("plan", "stage_in", "dispatch",
+                               "device_wait", "fetch"))
+    ids_t, scores_t = traced.to_numpy()
+    ids_p, scores_p = plain.to_numpy()
+    np.testing.assert_array_equal(ids_t, ids_p)
+    np.testing.assert_array_equal(scores_t, scores_p)
+    eng.store.close()
+
+
+def test_untraced_query_stages_reach_the_profiler(tmp_path):
+    """Under a profiler session an untraced query still emits its host
+    stages, as `micronn.*` events nested in the caller's annotation on
+    the profiler's clock."""
+    import jax
+
+    from chipbench import trace_reduce
+    eng, X = _mk(tmp_path, "profiled")
+    spec = Q.knn(k=5, n_probe=4)
+    eng.query(X[:1], spec).to_numpy()
+    tdir = str(tmp_path / "trace")
+    with jax.profiler.trace(tdir):
+        with jax.profiler.TraceAnnotation("window"):
+            for i in range(3):
+                with jax.profiler.TraceAnnotation("query"):
+                    rs = eng.query(X[i:i + 1], spec)
+                    rs.to_numpy()
+                assert rs.trace is None
+    names = ("query", "micronn.stage_in", "micronn.plan", "micronn.dispatch",
+             "micronn.device_wait", "micronn.fetch")
+    ev = trace_reduce.load(tdir, names)
+    queries = [e for e in ev if e.name == "query"]
+    assert len(queries) == 3
+    for name in names[1:]:
+        got = [e for e in ev if e.name == name]
+        assert len(got) == (6 if name == "micronn.stage_in" else 3), name
+        for e in got:
+            assert any(q.plane == e.plane and q.start_ns <= e.start_ns
+                       and e.end_ns <= q.end_ns for q in queries), name
     eng.store.close()
 
 

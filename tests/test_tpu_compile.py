@@ -10,6 +10,8 @@ The topology is described inside a fixture, never at import, so every
 test worker collects the same tests and only the one running this file
 loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -46,10 +48,16 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", before)
 
 
-def _compile(fn, shapes, sharding):
+def _compile(fn, shapes, sharding, kernel):
+    """Compile for the described chip; the Pallas call must lower to a
+    `tpu_custom_call` named after the kernel, the name a device trace
+    shows for it."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text), kernel
     return compiled
 
 
@@ -73,7 +81,7 @@ def test_ivf_scan_compiles_for_v5e(one_chip, d, q_n, k_parts, mqo,
         return ivf_scan.ivf_scan_topk(
             q, v, valid, ids, pids, k_out, qsel=qsel if mqo else None,
             attrs=attrs, attr_filter=pred, interpret=False)
-    _compile(fn, shapes, one_chip)
+    _compile(fn, shapes, one_chip, "ivf_scan_topk")
 
 
 @pytest.mark.parametrize("d,q_n,k_parts,with_norms", [
@@ -93,4 +101,4 @@ def test_sq_scan_compiles_for_v5e(one_chip, d, q_n, k_parts, with_norms):
         return sq_scan.sq_scan_topk(
             q, codes, lo, scale, valid, ids, pids, k_out, qsel=qsel,
             norms=norms if with_norms else None, interpret=False)
-    _compile(fn, shapes, one_chip)
+    _compile(fn, shapes, one_chip, "sq_scan_topk")
