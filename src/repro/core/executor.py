@@ -706,6 +706,17 @@ def _bucket(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
+def as_query_batch(queries):
+    """The [Q, d] float32 query batch, kept where the caller holds it: a
+    jax.Array stays on the device, anything else is cast on the host by
+    NumPy (the same float32 rounding as a device cast) and crosses to
+    the device inside the jitted call, with no eager device op of its
+    own."""
+    if isinstance(queries, jax.Array):
+        return jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
+    return np.atleast_2d(np.asarray(queries, np.float32))
+
+
 def _record_resident_spans(tr, index, spec: QuerySpec, Q: int, b: int,
                            compiled: int):
     """Probe/scan/rerank/merge spans for a traced resident query. The one
@@ -759,6 +770,12 @@ def run(index, queries: jax.Array, spec: QuerySpec, *,
     itself part of jit's implicit key). Paged execution streams the
     probe set through the frame pool (paged_search).
 
+    Host queries (NumPy, lists) are padded and masked with NumPy and
+    cross to the device inside the jitted call, so no eager device op
+    runs before it; a caller's jax.Array stays on the device and is
+    padded and masked there (`as_query_batch`). Both give the same
+    float32 bits, the same avals and so the same trace.
+
     A traced resident run does exactly the device work of an untraced
     one: the host stages `stage_in` and `dispatch` are timed around the
     same calls, and the fused stages get counter-only spans.
@@ -782,14 +799,17 @@ def run(index, queries: jax.Array, spec: QuerySpec, *,
             backend=spec.on_backend, quantized=spec.use_quantized,
             spec=spec)
     tr = obs_trace.current()
-    with obs_trace.stage(obs_trace.STAGE_STAGE_IN, tr):
-        q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
+    with obs_trace.stage(obs_trace.STAGE_STAGE_IN, tr) as st:
+        q = as_query_batch(queries)
         Q = q.shape[0]
         b = _bucket(Q) if bucket else Q
+        on_device = isinstance(q, jax.Array)
+        xp = jnp if on_device else np
         if b != Q:
-            q = jnp.concatenate([q, jnp.zeros((b - Q, q.shape[1]),
-                                              q.dtype)])
-        qmask = jnp.arange(b) < Q
+            q = xp.concatenate([q, xp.zeros((b - Q, q.shape[1]), q.dtype)])
+        qmask = xp.arange(b) < Q
+        (_C_STAGED_DEVICE if on_device else _C_STAGED_HOST).inc()
+        st.set(staged="device" if on_device else "host")
     tc0 = _TRACE_COUNT
     with obs_trace.stage(obs_trace.STAGE_DISPATCH, tr):
         res = _run_spec(index, q, qmask, spec)
@@ -813,11 +833,13 @@ def run_coalesced(index, chunks, spec: QuerySpec):
     solo `run()` would have returned -- pinned by tests/test_frontdoor
     and the gather-vs-union parity tests."""
     assert len(chunks) >= 1, "run_coalesced needs at least one chunk"
-    qs = [jnp.atleast_2d(jnp.asarray(c, jnp.float32)) for c in chunks]
+    qs = [as_query_batch(c) for c in chunks]
     sizes = [int(q.shape[0]) for q in qs]
     if len(qs) == 1:
         return [run(index, qs[0], spec)]
-    rs = run(index, jnp.concatenate(qs, axis=0), spec)
+    on_device = any(isinstance(q, jax.Array) for q in qs)
+    rs = run(index, (jnp if on_device else np).concatenate(qs, axis=0),
+             spec)
     return rs.split(sizes)
 
 
@@ -1156,3 +1178,6 @@ def paged_search(
 _OBS = obs_metrics.default_registry().scope(component="executor")
 _OBS.gauge("trace_count", fn=trace_count)
 _OBS.gauge("compile_cache_size", fn=compile_cache_size)
+# resident `run` calls, by where the query batch was padded and masked
+_C_STAGED_HOST = _OBS.counter("queries_staged_host")
+_C_STAGED_DEVICE = _OBS.counter("queries_staged_device")

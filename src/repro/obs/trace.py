@@ -17,9 +17,12 @@ ONE hook, `stage(name, trace)`, which writes to two sinks:
 Stages, in the order a resident query crosses them:
 
     stage_in      host work before the jitted call, less `plan`: the
-                  query's host->device copy and the query counter
-                  (engine), bucket padding and the query mask (executor);
-                  two recordings, calls=2
+                  query's float32 cast and the query counter (engine),
+                  bucket padding and the query mask (executor); two
+                  recordings, calls=2. Resident `staged` says where:
+                  "host" (NumPy; the copy to the device happens inside
+                  the jitted call) or "device" (a caller's jax.Array,
+                  eager device ops)
     plan          spec resolution (hybrid pre/post choice), kind, k
     dispatch      the jitted `_run_spec` call, until it returns
     device_wait   ResultSet.to_numpy(): block until ids + scores are ready

@@ -877,7 +877,7 @@ class MicroNN:
         assert idx is not None, "build() or recover() first"
         spec = QuerySpec() if spec is None else spec
         with obs_trace.stage(obs_trace.STAGE_STAGE_IN, obs_trace.current()):
-            q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
+            q = executor.as_query_batch(queries)
             self._c_queries.inc()
         spec = self._resolve_spec_traced(idx, optimizer, spec,
                                          int(q.shape[0]))

@@ -19,6 +19,7 @@ Pins the layer's three contracts:
 import re
 import threading
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -259,6 +260,7 @@ def test_traced_resident_query_does_no_device_work_of_its_own(
         assert stage in tr, (stage, tr.span_names)
         assert tr.get(stage).dur_ms > 0, stage
     assert tr.get("stage_in").calls == 2        # engine part + executor part
+    assert tr.counter("stage_in", "staged") == "host"
     # the fused stages are counter-only markers
     for stage in ("probe", "scan", "rerank", "merge"):
         assert tr.get(stage).dur_ms == 0.0 and tr.counter(stage, "fused") == 1
@@ -271,6 +273,14 @@ def test_traced_resident_query_does_no_device_work_of_its_own(
     ids_p, scores_p = plain.to_numpy()
     np.testing.assert_array_equal(ids_t, ids_p)
     np.testing.assert_array_equal(scores_t, scores_p)
+    # a caller's device array keeps the device staging, same answer
+    on_dev = eng.query(jnp.asarray(q), spec, trace=True)
+    assert on_dev.trace.get("stage_in").calls == 2
+    assert on_dev.trace.counter("stage_in", "staged") == "device"
+    ids_d, scores_d = on_dev.to_numpy()
+    np.testing.assert_array_equal(ids_d, ids_p)
+    np.testing.assert_array_equal(scores_d, scores_p)
+    assert executor.trace_count() == c0
     eng.store.close()
 
 
