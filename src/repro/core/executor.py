@@ -202,9 +202,8 @@ def plan_ann_gather(index: IVFIndex, queries: jax.Array, k: int,
     scores it directly -- the seed's formulation, which beats the shared
     union below SMALL_Q_GATHER_MAX queries on CPU (no vote/top-k union
     plumbing, no scan over other queries' partitions). Same candidate
-    set as plan_ann at equal n_probe, so recall is identical; parity is
-    pinned by tests (ids equal, scores allclose -- a differently-shaped
-    matmul is not bitwise-identical to the union scan)."""
+    set as plan_ann at equal n_probe, so recall is identical, and the
+    reported float32 scores are the union scan's bits (`_topk_rescored`)."""
     cfg = index.config
     q = normalize_if_cosine(queries.astype(jnp.float32), cfg.metric)
     parts = find_nearest_centroids(index, q, n_probe)      # [Q, n]
@@ -279,11 +278,38 @@ def fused_scan(
                      attr_filter=attr_filter)
 
 
+def _topk_rescored(q, scores, rows, ids, k, metric):
+    """Top-k of a scan's [Q, N] scores, each winner then scored again
+    from its own (query, row) pair: a multiply and a reduction over d.
+    The scan's matmul picks the winners, but its bits depend on how many
+    queries and rows share the call; these do not, so the resident union
+    scan, the small-Q gather and the paged frame chunks report the same
+    float32 score for the same pair. `rows` [N, d] and `ids` [N] are the
+    scanned rows (or [Q, N, d] and [Q, N], each query's own)."""
+    pos = jnp.broadcast_to(jnp.arange(scores.shape[-1], dtype=jnp.int32),
+                           scores.shape)
+    s, pos = topk_smallest(scores, pos, k)
+    at = jnp.maximum(pos, 0)
+    if rows.ndim == 2:
+        x, ids = rows[at], ids[at]
+    else:
+        x = jnp.take_along_axis(rows, at[..., None], axis=1)
+        ids = jnp.take_along_axis(ids, at, axis=1)
+    dots = jnp.sum(q[:, None, :] * x, axis=-1)
+    if metric in ("ip", "cosine"):
+        exact = -dots
+    else:
+        exact = jnp.sum(x * x, axis=-1) - 2.0 * dots
+    s = jnp.where(pos == INVALID_ID, s, exact)
+    return topk_smallest(s, jnp.where(pos == INVALID_ID, INVALID_ID, ids), k)
+
+
 def _xla_scan_gathered(queries, pv, pok, pid, k_out, *, metric, qsel=None,
                        pattrs=None, attr_filter=None):
     """Shared core of the XLA reference backends, over the already-
     gathered probe union ([n, p_max, d]): one [Q, d] x [d, n*p_max]
-    matmul, predicate + selection masking, top-k."""
+    matmul, predicate + selection masking, top-k, the winners rescored
+    pair by pair (`_topk_rescored`)."""
     if attr_filter is not None:
         pok = pok & attr_filter(pattrs)
     n, p_max, d = pv.shape
@@ -298,8 +324,8 @@ def _xla_scan_gathered(queries, pv, pok, pid, k_out, *, metric, qsel=None,
     if qsel is not None:
         ok = ok & jnp.repeat(qsel, p_max, axis=1)
     scores = mask_scores(scores, ok)
-    return topk_smallest(
-        scores, jnp.broadcast_to(pid.reshape(1, -1), scores.shape), k_out)
+    return _topk_rescored(queries, scores, flat_v, pid.reshape(-1), k_out,
+                          metric)
 
 
 def _xla_scan(queries, vectors, valid, ids, part_ids, k_out, *, metric,
@@ -611,9 +637,10 @@ def execute_plan(index: IVFIndex, plan: QueryPlan,
             scores = mask_scores(scores.reshape(q.shape[0], npb * p_max),
                                  pok.reshape(q.shape[0], npb * p_max))
             k_scan = min(plan.k, npb * p_max)
-            s, i = topk_smallest(
-                scores, index.ids[parts].reshape(q.shape[0], npb * p_max),
-                k_scan)
+            s, i = _topk_rescored(
+                q, scores, pv.reshape(q.shape[0], npb * p_max, d),
+                index.ids[parts].reshape(q.shape[0], npb * p_max), k_scan,
+                cfg.metric)
     elif use_sq:
         # Two-stage quantized search: (1) fused SQ scan over int8 codes
         # selects k' = rerank_factor * k candidate rows; (2) exact f32

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import kmeans, quantize
+from . import quantize
 from .types import (DeltaStore, INVALID_ID, IVFConfig, IVFIndex,
                     effective_pad_to, normalize_if_cosine)
 
@@ -77,17 +77,35 @@ def build_index(
     k: Optional[int] = None,
     qstats: Optional[quantize.QuantStats] = None,
 ) -> IVFIndex:
-    """Full index build: Alg. 1 clustering + partition-major packing.
+    """Full index build: Alg. 1 clustering, the split pass to
+    `FRAME_ROWS_MAX` rows a partition, partition-major packing.
 
     With cfg.quantize == "int8" the build also trains the scalar quantizer
     (unless pre-trained stats are passed, e.g. streamed from the durable
     store) and encodes every row into the code tier.
     """
+    return build_with_split(X, ids, attrs, cfg, k, qstats)[0]
+
+
+def build_with_split(
+    X: np.ndarray,
+    ids: Optional[np.ndarray] = None,
+    attrs: Optional[np.ndarray] = None,
+    cfg: Optional[IVFConfig] = None,
+    k: Optional[int] = None,
+    qstats: Optional[quantize.QuantStats] = None,
+):
+    """`build_index`, also returning what its split pass did (a
+    maintenance.BuildSplitStats, for the engine's build counters)."""
+    from . import maintenance           # maintenance imports this module
     cfg = cfg or IVFConfig(dim=X.shape[1])
-    X = np.asarray(
-        normalize_if_cosine(jnp.asarray(X, jnp.float32), cfg.metric))
+    X = np.asarray(X, np.float32)
     n = X.shape[0]
     ids = np.arange(n, dtype=np.int32) if ids is None else ids.astype(np.int32)
+    # the clustering sees the raw rows, as the paged build streams them
+    centroids, csizes, assign, split = maintenance.fit_to_bar(X, ids, cfg,
+                                                              k=k)
+    X = np.asarray(normalize_if_cosine(jnp.asarray(X), cfg.metric))
 
     codes = None
     if cfg.quantize == "int8":
@@ -97,7 +115,6 @@ def build_index(
     else:
         qstats = None
 
-    centroids, csizes, assign = kmeans.fit_in_memory(X, cfg, k=k)
     k = centroids.shape[0]
     # dtype-aware tile padding: int8 partitions on real TPU pad to the
     # (32, 128) minimum tile; f32 / interpret keep cfg.pad_to
@@ -123,7 +140,7 @@ def build_index(
                                                                code_tier),
         drift=jnp.zeros((k,), jnp.float32),
         config=cfg,
-    )
+    ), split
 
 
 def grow_layout(index: IVFIndex, new_p_max: int) -> IVFIndex:

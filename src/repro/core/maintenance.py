@@ -33,15 +33,17 @@ device.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ivf, quantize
-from .types import (DeltaStore, INVALID_ID, IVFConfig, IVFIndex,
-                    effective_pad_to, pairwise_scores)
+from ..obs import trace as obs_trace
+from . import ivf, kmeans, quantize
+from .types import (DeltaStore, FRAME_ROWS_MAX, INVALID_ID, IVFConfig,
+                    IVFIndex, effective_pad_to, pairwise_scores)
 
 
 @dataclasses.dataclass
@@ -443,6 +445,110 @@ def plan_split(centroids: np.ndarray, csizes: np.ndarray,
     if plan is None or not plan.moved.any():
         return None
     return plan
+
+
+@dataclasses.dataclass
+class BuildSplitStats:
+    """What the build's split pass did (`split_to_bar`)."""
+
+    splits: int = 0           # 2-means splits applied
+    rows_split: int = 0       # rows of each split partition, summed over
+    #                           every split (a row split twice counts twice)
+    unsplittable: int = 0     # partitions left above the bar (rows all equal)
+    max_rows: int = 0         # largest partition after the pass
+    ms: float = 0.0           # host time of the pass
+
+
+def _unit_rows(rows: np.ndarray, metric: str) -> np.ndarray:
+    """Host metric normalisation, row by row (NumPy: no device op and no
+    compile per partition size)."""
+    rows = np.asarray(rows, np.float32)
+    if metric != "cosine":
+        return rows
+    n = np.linalg.norm(rows, axis=-1, keepdims=True)
+    return rows / np.maximum(n, np.float32(1e-12))
+
+
+def split_to_bar(centroids: np.ndarray, csizes: np.ndarray,
+                 ids: np.ndarray, assign: np.ndarray,
+                 rows_for: Callable[[np.ndarray], np.ndarray], metric: str
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
+                            BuildSplitStats]:
+    """The build's split pass: 2-means-split (`plan_split`, the monitor's
+    own split) every partition above `FRAME_ROWS_MAX` rows, and each
+    piece again, until none is above it. Runs on a fresh clustering
+    before it is persisted, so p_max stays where the paged frame scan
+    compiles.
+
+    `ids` and `assign` are aligned [n] arrays over the build's rows;
+    `rows_for(pos)` returns the raw float32 rows at those positions. One
+    oversized partition is read at a time, its rows sorted by asset id,
+    so host memory is O(largest partition) and the resident and paged
+    builds, fed the same rows, split identically. Returns the new
+    (centroids, csizes, assign) and what was done; k grows by the splits
+    that found no empty slot to reuse."""
+    t0 = time.perf_counter()
+    tr = obs_trace.current()
+    with obs_trace.stage(obs_trace.STAGE_BUILD_SPLIT, tr) as st:
+        assign = np.array(assign, np.int64)
+        k = centroids.shape[0]
+        counts = np.bincount(assign, minlength=k).astype(np.int64)
+        order = np.argsort(assign, kind="stable")
+        starts = np.concatenate([[0], np.cumsum(counts)])
+        # grown by doubling: a split appends at most one slot
+        cents = np.array(centroids, np.float32)
+        csz = np.array(csizes, np.float32)
+        stats = BuildSplitStats()
+        for p0 in np.nonzero(counts > FRAME_ROWS_MAX)[0]:
+            pos = order[starts[p0]:starts[p0 + 1]]
+            pos = pos[np.argsort(ids[pos], kind="stable")]
+            vecs = _unit_rows(rows_for(pos), metric)
+            members = {int(p0): np.arange(len(pos))}
+            todo = [int(p0)]
+            while todo:
+                p = todo.pop()
+                sel = members[p]
+                block = RowBlock(ids=ids[pos[sel]].astype(np.int32),
+                                 vecs=vecs[sel])
+                plan = plan_split(cents[:k], csz[:k], counts[:k], p,
+                                  lambda pids, p=p, b=block: {p: b},
+                                  n_local=0)
+                if plan is None:
+                    stats.unsplittable += 1
+                    continue
+                new = int(plan.new_pid)
+                if new >= len(cents):
+                    grow = len(cents)
+                    cents = np.pad(cents, [(0, grow), (0, 0)])
+                    csz = np.pad(csz, (0, grow))
+                    counts = np.pad(counts, (0, grow))
+                k = max(k, plan.k_after)
+                cents[plan.pids] = plan.centroids
+                csz[plan.pids] = plan.csizes
+                assign[pos[sel]] = plan.assign
+                stats.splits += 1
+                stats.rows_split += len(sel)
+                for q in (p, new):
+                    members[q] = sel[plan.assign == q]
+                    counts[q] = len(members[q])
+                    if counts[q] > FRAME_ROWS_MAX:
+                        todo.append(q)
+        stats.max_rows = int(counts[:k].max()) if k else 0
+        stats.ms = (time.perf_counter() - t0) * 1e3
+        st.set(**dataclasses.asdict(stats))
+    return cents[:k], csz[:k], assign, stats
+
+
+def fit_to_bar(X: np.ndarray, ids: np.ndarray, cfg: IVFConfig,
+               k: Optional[int] = None
+               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
+                          BuildSplitStats]:
+    """The in-memory build's clustering (ivf.build_index): Alg. 1 over the
+    raw rows, then `split_to_bar` to `FRAME_ROWS_MAX`. The paged build
+    streams the same two steps from SQLite and gets the same result."""
+    cents, csz, assign = kmeans.fit_in_memory(X, cfg, k=k)
+    return split_to_bar(cents, csz, ids, assign, lambda pos: X[pos],
+                        cfg.metric)
 
 
 def choose_merge_partner(centroids: np.ndarray, counts: np.ndarray,

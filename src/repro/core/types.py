@@ -79,6 +79,17 @@ class IVFConfig:
     seed: int = static_field(default=0)
 
 
+# Rows of the largest partition a build leaves (maintenance.split_to_bar):
+# what the paged int8 frame scan can tile. With no resident norms it
+# decodes each probed frame in-kernel, and the compiled decode takes about
+# 17 KB of scoped VMEM per padded row, whatever d: a v5e's 16 MiB holds
+# p_max 896 at d = 128 and overflows at 928 (16.15 MiB at k_out 1,024),
+# and the cliff wanders with d (832 overflows at d = 960, 800 at 1,536).
+# 768 compiles for d = 128 to 1,536 and k_out up to 1,024
+# (tests/test_tpu_compile.py).
+FRAME_ROWS_MAX = 768
+
+
 def effective_pad_to(cfg: "IVFConfig", backend: Optional[str] = None) -> int:
     """Dtype-aware Pallas tile padding for the partition axis.
 

@@ -135,13 +135,15 @@ def distributed_query(
 
         # -- phase 3: scan owned probed partitions --------------------------
         mine = (gi // k_local) == me                          # [Q, n]
-        lid = jnp.where(mine, gi % k_local, 0)
+        # other shards' probes point past the end and are dropped (mapped
+        # to local partition 0 they raced its own probe in the scatter)
+        lid = jnp.where(mine, gi % k_local, k_local)
         # fixed-cap compaction of this device's probe list over the batch
-        want = jnp.zeros((k_local,), bool).at[
-            jnp.where(mine, lid, 0).reshape(-1)].set(
-            mine.reshape(-1), mode="drop")
+        want = jnp.zeros((k_local,), bool).at[lid.reshape(-1)].set(
+            True, mode="drop")
         (plist,) = jnp.nonzero(want, size=cap, fill_value=0)
-        pvalid_probe = jnp.take(want, plist)
+        # the fill repeats partition 0: only the first want.sum() are real
+        pvalid_probe = jnp.arange(cap) < want.sum()
 
         # per-query selection: query q wants local partition plist[j]?
         sel = (gi[:, None, :] == (plist[None, :, None] + me * k_local)

@@ -40,6 +40,11 @@ Stages, in the order a resident query crosses them:
     queue_wait /  front-door requests only: admission latency and the
     split         coalesced-batch sub-span (callers, batch rows)
 
+One stage belongs to the build, not to a query: `build_split`, the
+build's pass that splits every partition above FRAME_ROWS_MAX rows
+(maintenance.split_to_bar; counters splits, rows_split, unsplittable,
+max_rows, ms). Record it by activating a QueryTrace around `build()`.
+
 Off-path cost: with no active trace and no profiler collecting, a hook
 is one module-bool test plus the profiler check (~0.1 us together) and
 returns the shared no-op `OFF` stage: NO span objects, dicts, or registry
@@ -79,6 +84,7 @@ STAGE_RERANK = "rerank"
 STAGE_MERGE = "merge"
 STAGE_QUEUE = "queue_wait"
 STAGE_SPLIT = "split"
+STAGE_BUILD_SPLIT = "build_split"
 # profiler-side name of a stage: PROFILER_PREFIX + stage
 PROFILER_PREFIX = "micronn."
 

@@ -236,7 +236,10 @@ class MicroNN:
     @_locked
     def build(self):
         """Initial clustering from the durable tier (mini-batch k-means
-        streams from SQLite -- never the full dataset in memory). With
+        streams from SQLite -- never the full dataset in memory), then
+        the split pass: every partition above FRAME_ROWS_MAX rows is
+        2-means-split until none is (maintenance.split_to_bar; p_max stays
+        where the paged frame scan compiles). With
         quantize="int8" the build also trains the quantizer from the
         store's rows (build_index trains min/max on the same data, so no
         second pass over SQLite) and persists codes + stats durably
@@ -244,12 +247,13 @@ class MicroNN:
         codes table is always decode-consistent with the stored qstats.
         """
         if self.paged:
-            self._build_paged()
+            self.scheduler.record_build(self._build_paged())
             return
         ids, _, vecs = self.store.all_rows()
         attrs = self.store.attributes_for(ids)
-        self.index = ivf.build_index(
+        self.index, split = ivf.build_with_split(
             vecs, ids.astype(np.int32), attrs, cfg=self.config)
+        self.scheduler.record_build(split)
         self._persist_codes()
         # persist the clustering back to the clustered table
         assign = self._current_assignment()
@@ -1036,7 +1040,9 @@ class MicroNN:
         final assignment streams the clustered scan, and the generation
         swap moves partition ids with keyed UPDATEs instead of
         re-materialising the blobs. Same crash ordering as build():
-        codes + qstats land before the clustering swap."""
+        codes + qstats land before the clustering swap. The split pass
+        reads one oversized partition at a time back from SQLite and
+        folds into the same swap. Returns the split pass's stats."""
         cfg = self.config
         store = self.store
         batch = max(cfg.minibatch_size, 4096)
@@ -1059,11 +1065,15 @@ class MicroNN:
         km = kmeans.MiniBatchKMeans(cfg)
         km.fit(lambda size, rng: store.sample(size, rng), len(ids))
         assign = km.assign(store.iter_batches(batch))
-        store.reassign_partitions(ids, assign, km.centroids, km.counts)
+        cents, csz, assign, split = maintenance.split_to_bar(
+            km.centroids, km.counts, ids, assign,
+            lambda pos: store.vectors_for(ids[pos])[0], cfg.metric)
+        store.reassign_partitions(ids, assign, cents, csz)
         self._attach_paged()
         # a fresh clustering resets the maintenance signals -- write them
         # so a later recover() does not restore a stale pre-build state
         self._persist_maintenance_state()
+        return split
 
     def _attach_paged(self):
         """Build the PagedIndex view from durable metadata only: centroids,

@@ -52,10 +52,20 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+import weakref
 from typing import Callable, List, Optional, Tuple
+
+import numpy as np
 
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
+
+
+def _partition_max_rows(engine) -> int:
+    idx = None if engine is None else engine.index
+    if idx is None or not idx.k:
+        return 0
+    return int(np.asarray(idx.counts).max())
 
 
 @dataclasses.dataclass
@@ -106,6 +116,17 @@ class MaintenanceScheduler:
         self._c_bytes_written = metrics.counter("bytes_written")
         self._c_actions = {a: metrics.counter("action_steps", action=a)
                            for a in self._ACTIONS}
+        # the build's split pass (maintenance.split_to_bar): the split half
+        # of maintenance, done before the clustering is persisted
+        self._c_build_splits = metrics.counter("build_splits")
+        self._c_build_rows_split = metrics.counter("build_rows_split")
+        self._c_build_unsplittable = metrics.counter("build_unsplittable")
+        self._g_build_split_ms = metrics.gauge("build_split_ms")
+        # largest partition of the live index, read when read (a weak
+        # reference: the process registry must not keep an engine alive)
+        ref = weakref.ref(engine)
+        self._g_partition_max = metrics.gauge(
+            "partition_max_rows", fn=lambda: _partition_max_rows(ref()))
         # (action, pids, rows) triples that planned to a no-op within the
         # current run of fruitless polls; cleared whenever any step makes
         # progress, so changed row contents (or a remapped clustering
@@ -195,9 +216,17 @@ class MaintenanceScheduler:
             return report
         return None
 
+    def record_build(self, split):
+        """Count what a build's split pass did (a BuildSplitStats)."""
+        self._c_build_splits.inc(split.splits)
+        self._c_build_rows_split.inc(split.rows_split)
+        self._c_build_unsplittable.inc(split.unsplittable)
+        self._g_build_split_ms.set(split.ms)
+
     def stats(self) -> dict:
         """The scheduler's registry-backed telemetry (surfaced through
-        MicroNN.stats()['scheduler'])."""
+        MicroNN.stats()['scheduler']), the build's split-pass counters
+        and the largest partition's rows among them."""
         return {"wakeups": self._c_wakeups.value,
                 "idle_probes": self._c_idle_probes.value,
                 "busy_backoffs": self._c_busy_backoffs.value,
@@ -207,7 +236,12 @@ class MaintenanceScheduler:
                 "bytes_written": self._c_bytes_written.value,
                 "daemon_errors": self.daemon_errors,
                 "actions": {a: c.value
-                            for a, c in self._c_actions.items()}}
+                            for a, c in self._c_actions.items()},
+                "build_splits": self._c_build_splits.value,
+                "build_rows_split": self._c_build_rows_split.value,
+                "build_unsplittable": self._c_build_unsplittable.value,
+                "build_split_ms": self._g_build_split_ms.value,
+                "partition_max_rows": int(self._g_partition_max.value)}
 
     def drain(self, max_steps: Optional[int] = None) -> List[StepReport]:
         """Run steps until the queue is idle (maintain(until_idle=True)).

@@ -570,11 +570,16 @@ class VectorStore:
             yield np.stack([np.frombuffer(r[0], np.float32) for r in rows])
 
     def sample(self, size: int, rng: np.random.Generator) -> np.ndarray:
-        """Uniform random row sample (mini-batch k-means feed)."""
+        """Uniform random row sample (mini-batch k-means feed), in the
+        order drawn: the rows `kmeans.fit_in_memory` samples from the same
+        rows in scan order, so the paged and resident builds see the same
+        batches."""
         n = self.count()
         if n == 0:
             return np.zeros((0, self.dim), np.float32)
-        idx = sorted(int(i) for i in rng.integers(0, n, size=size))
+        draw = rng.integers(0, n, size=size)
+        order = np.argsort(draw, kind="stable")
+        idx = draw[order].tolist()
         out = []
         cur = self.db.execute(
             "SELECT vec FROM vectors ORDER BY partition_id, asset_id")
@@ -586,7 +591,11 @@ class VectorStore:
                 nxt = next(want, None)
             if nxt is None:
                 break
-        return np.stack(out) if out else np.zeros((0, self.dim), np.float32)
+        if not out:
+            return np.zeros((0, self.dim), np.float32)
+        rows = np.empty((len(out), self.dim), np.float32)
+        rows[order] = np.stack(out)
+        return rows
 
     def all_rows(self):
         rows = self.db.execute(

@@ -4,7 +4,8 @@ No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached, and refuses what the chip would refuse (block
 tiling, VMEM over the scoped limit, ops Mosaic cannot lower). Interpret
 mode cannot show any of that. The shapes are the widths the deployments
-use: SIFT (d=128) and GIST (d=960), p_max as the SIFT-1M build gives it.
+use: SIFT (d=128) and GIST (d=960), and the paged frame scan at the
+largest frame a build leaves.
 
 The topology is described inside a fixture, never at import, so every
 test worker collects the same tests and only the one running this file
@@ -18,10 +19,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.hybrid import And, Pred, compile_filter
+from repro.core.types import FRAME_ROWS_MAX
 from repro.kernels import ivf_scan, sq_scan
 
 # p_max of the SIFT-1M build (1,000,000 x 128, target partition size 100,
-# int8 padding to 32) as chip_smoke.py printed it on a v5e
+# int8 padding to 32) as chip_smoke.py printed it on a v5e on a seed where
+# the k-means' largest partition was under FRAME_ROWS_MAX; a build now
+# splits every partition above that bar
 P_MAX = 576
 K_PARTS = 10_000
 
@@ -101,4 +105,28 @@ def test_sq_scan_compiles_for_v5e(one_chip, d, q_n, k_parts, with_norms):
         return sq_scan.sq_scan_topk(
             q, codes, lo, scale, valid, ids, pids, k_out, qsel=qsel,
             norms=norms if with_norms else None, interpret=False)
+    _compile(fn, shapes, one_chip, "sq_scan_topk")
+
+
+@pytest.mark.parametrize("d", [128, 256, 960])
+def test_frame_pool_sq_scan_compiles_for_v5e(one_chip, d):
+    """The paged int8 frame scan as `executor._scan_frames_sq` runs it: L2,
+    no resident norms (each frame decodes in-kernel), one query and its 8
+    probed frames, at the largest frame a build leaves (FRAME_ROWS_MAX)
+    and the widest candidate list the bar is sized for (k_out 1,024: top
+    256 at rerank factor 4). The frame pool is the 10 MiB deployment's at
+    d = 128 (p_max x 141 B a frame)."""
+    q_n, n_probe, k_out = 1, 8, 1024
+    frames = 10 * 2 ** 20 // (FRAME_ROWS_MAX * (d + 13))
+    shapes = [((q_n, d), jnp.float32),
+              ((frames, FRAME_ROWS_MAX, d), jnp.int8),
+              ((d,), jnp.float32), ((d,), jnp.float32),
+              ((frames, FRAME_ROWS_MAX), jnp.bool_),
+              ((frames, FRAME_ROWS_MAX), jnp.int32),
+              ((n_probe,), jnp.int32), ((q_n, n_probe), jnp.bool_)]
+
+    def fn(q, codes, lo, scale, valid, ids, frames, qsel):
+        return sq_scan.sq_scan_topk(
+            q, codes, lo, scale, valid, ids, frames, k_out, metric="l2",
+            qsel=qsel, norms=None, interpret=False)
     _compile(fn, shapes, one_chip, "sq_scan_topk")
