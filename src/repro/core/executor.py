@@ -71,7 +71,8 @@ from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from .hybrid import compile_filter
 from .query import QuerySpec, ResultSet
-from .topk import dedup_by_id, mask_scores, merge_topk, topk_smallest
+from .topk import (dedup_by_id, mask_scores, merge_topk,
+                   running_topk_init, topk_smallest)
 from .types import (EXACT, INVALID_ID, MASKED_SCORE, IVFIndex, PagedIndex,
                     SearchResult, normalize_if_cosine, pairwise_scores,
                     register_dataclass, static_field)
@@ -744,6 +745,22 @@ def as_query_batch(queries):
     return np.atleast_2d(np.asarray(queries, np.float32))
 
 
+def _stage_batch(queries, bucket: bool = True):
+    """The query batch padded to its bucket and the mask of its real rows:
+    (q [b, d] float32, qmask [b] bool, Q, on_device). A host query is
+    padded and masked with NumPy and crosses to the device inside the
+    jitted call that takes it; a caller's jax.Array is padded and masked
+    on the device (`as_query_batch`). Both give the same float32 bits."""
+    q = as_query_batch(queries)
+    Q = q.shape[0]
+    b = _bucket(Q) if bucket else Q
+    on_device = isinstance(q, jax.Array)
+    xp = jnp if on_device else np
+    if b != Q:
+        q = xp.concatenate([q, xp.zeros((b - Q, q.shape[1]), q.dtype)])
+    return q, xp.arange(b) < Q, Q, on_device
+
+
 def _record_resident_spans(tr, index, spec: QuerySpec, Q: int, b: int,
                            compiled: int):
     """Probe/scan/rerank/merge spans for a traced resident query. The one
@@ -827,14 +844,8 @@ def run(index, queries: jax.Array, spec: QuerySpec, *,
             spec=spec)
     tr = obs_trace.current()
     with obs_trace.stage(obs_trace.STAGE_STAGE_IN, tr) as st:
-        q = as_query_batch(queries)
-        Q = q.shape[0]
-        b = _bucket(Q) if bucket else Q
-        on_device = isinstance(q, jax.Array)
-        xp = jnp if on_device else np
-        if b != Q:
-            q = xp.concatenate([q, xp.zeros((b - Q, q.shape[1]), q.dtype)])
-        qmask = xp.arange(b) < Q
+        q, qmask, Q, on_device = _stage_batch(queries, bucket)
+        b = q.shape[0]
         (_C_STAGED_DEVICE if on_device else _C_STAGED_HOST).inc()
         st.set(staged="device" if on_device else "host")
     tc0 = _TRACE_COUNT
@@ -914,7 +925,8 @@ def search(
 # is faulted on demand into a storage/pager.PartitionCache. Execution is
 # host-driven: (1) pick the probe set from the resident centroids with the
 # SAME vote/union ordering as plan_ann -- this is what pins paged-vs-
-# resident parity bit-for-bit; (2) fault each probe chunk (<= pool
+# resident parity bit-for-bit -- in one compiled call (_paged_plan) that
+# also stages the query and the running top-k; (2) fault each probe chunk (<= pool
 # capacity) and run the fused scan over the pool with *frame* indices as
 # the scalar-prefetched probe list (the frame -> partition indirection --
 # both kernels are layout-agnostic, they just stream whichever blocks the
@@ -969,16 +981,39 @@ def _rerank_from_store(store, q: jax.Array, cand_ids: jax.Array,
     return out
 
 
-def _paged_probes(pindex, q: jax.Array, n_probe: int,
-                  qmask: Optional[jax.Array] = None):
-    """plan_ann's probe construction over a PagedIndex's resident metadata
-    -- literally _probe_union (shared with plan_ann), so paged and
-    resident searches agree on the probe order."""
-    counts = jnp.asarray(np.asarray(pindex.counts), jnp.int32)
-    upart, qsel = _probe_union(pindex.centroids, counts,
-                               pindex.config.metric, q, n_probe,
+def _run_width(k_want: int, n_parts: int, p_max: int) -> int:
+    """Width of the paged running top-k: the k' the scan is asked for,
+    capped by the rows the probe list can hold."""
+    return min(k_want, max(n_parts * p_max, 1))
+
+
+# Retrace counter of the paged planner (`_paged_plan`), kept apart from
+# _TRACE_COUNT, which counts `_run_spec` compiles only.
+_PAGED_PLAN_TRACE_COUNT = 0
+
+
+def paged_plan_trace_count() -> int:
+    return _PAGED_PLAN_TRACE_COUNT
+
+
+@partial(jax.jit, static_argnames=("n_probe", "metric", "k_want", "p_max"))
+def _paged_plan(queries, qmask, centroids, counts, *, n_probe, metric,
+                k_want, p_max):
+    """The paged planner as one compiled call, from the staged query batch
+    to the probe list: the metric-normalised query, the query mask,
+    plan_ann's probe union over the resident centroids -- literally
+    _probe_union, shared with plan_ann, so paged and resident searches
+    agree on the probe order -- and the empty running top-k buffers.
+    `counts` are the live rows per partition as the host holds them, an
+    argument of every call: no device copy can go stale under a write."""
+    global _PAGED_PLAN_TRACE_COUNT
+    _PAGED_PLAN_TRACE_COUNT += 1      # executes only while tracing
+    q = normalize_if_cosine(queries, metric)
+    upart, qsel = _probe_union(centroids, counts, metric, q, n_probe,
                                qmask=qmask)
-    return np.asarray(upart, np.int64), qsel
+    run_s, run_i = running_topk_init(
+        q.shape[:1], _run_width(k_want, upart.shape[0], p_max))
+    return q, qmask, upart, qsel, run_s, run_i
 
 
 @partial(jax.jit, static_argnames=("k", "k_scan", "metric", "attr_filter"))
@@ -1058,14 +1093,7 @@ def paged_search(
     """
     cfg = pindex.config
     cache = pindex.cache
-    q = normalize_if_cosine(
-        jnp.atleast_2d(jnp.asarray(queries, jnp.float32)), cfg.metric)
-    Q = q.shape[0]
-    b = _bucket(Q)
-    if b != Q:
-        q = jnp.concatenate([q, jnp.zeros((b - Q, q.shape[1]), q.dtype)])
-    qmask = jnp.arange(b) < Q
-
+    p_max = cache.p_max
     # the pool payload dictates the scan: an int8 pool can only run the SQ
     # scan (there are no f32 frames to brute-force -- paged "exact" on a
     # quantized index scans every partition's codes and reranks, a
@@ -1075,26 +1103,36 @@ def paged_search(
         assert quantized == use_sq, \
             f"paged scan tier is fixed by the frame pool payload " \
             f"({pindex.cache.payload}); cannot force quantized={quantized}"
+    k_want = max(k, k * cfg.rerank_factor) if use_sq else k
 
     tr = obs_trace.current()
     with obs_trace.stage(obs_trace.STAGE_PROBE, tr) as st:
+        q, qmask, Q, on_device = _stage_batch(queries)
+        b = q.shape[0]
         if kind == "exact":
+            q = normalize_if_cosine(jnp.asarray(q), cfg.metric)
+            qmask = jnp.asarray(qmask)
             counts = np.asarray(pindex.counts)
             upart = np.nonzero(counts > 0)[0]
             qsel = jnp.broadcast_to(qmask[:, None], (b, len(upart)))
+            k_run = _run_width(k_want, len(upart), p_max)
+            run_s, run_i = running_topk_init((b,), k_run)
         else:
             assert kind == "ann", kind
-            upart, qsel = _paged_probes(pindex, q, n_probe, qmask=qmask)
+            # one compiled call from the staged query to the probe list;
+            # the host fetches only the partition ids it must fault
+            q, qmask, upart, qsel, run_s, run_i = _paged_plan(
+                q, qmask, pindex.centroids,
+                np.asarray(pindex.counts, np.int32), n_probe=n_probe,
+                metric=cfg.metric, k_want=k_want, p_max=p_max)
+            upart = np.asarray(upart, np.int64)
+            k_run = run_s.shape[1]
+            _C_PLANS_FUSED.inc()
         n = len(upart)
         if tr is not None:
-            st.set(partitions=int(n), n_probe=int(n_probe), kind=kind)
-    p_max = cache.p_max
-    if use_sq:
-        k_run = min(max(k, k * cfg.rerank_factor), max(n * p_max, 1))
-    else:
-        k_run = min(k, max(n * p_max, 1))
-    run_s = jnp.full((b, k_run), MASKED_SCORE, jnp.float32)
-    run_i = jnp.full((b, k_run), INVALID_ID, jnp.int32)
+            st.set(partitions=int(n), n_probe=int(n_probe), kind=kind,
+                   fused=int(kind != "exact"),
+                   staged="device" if on_device else "host")
 
     if attr_filter is not None:
         assert cache.attrs_pool is not None, \
@@ -1208,3 +1246,6 @@ _OBS.gauge("compile_cache_size", fn=compile_cache_size)
 # resident `run` calls, by where the query batch was padded and masked
 _C_STAGED_HOST = _OBS.counter("queries_staged_host")
 _C_STAGED_DEVICE = _OBS.counter("queries_staged_device")
+# paged ANN runs planned by the one compiled call, and its compiles
+_C_PLANS_FUSED = _OBS.counter("paged_plans_fused")
+_OBS.gauge("paged_plan_trace_count", fn=paged_plan_trace_count)

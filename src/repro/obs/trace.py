@@ -36,7 +36,10 @@ Stages, in the order a resident query crosses them:
     pager_fault   paged only: frames hit/missed/staged-consumed, bytes
                   read from SQLite, accumulated over every chunk fault
     probe / scan  paged: timed per stage (the host drives each chunk;
-    rerank/merge  a traced paged query blocks at each stage end)
+    rerank/merge  a traced paged query blocks at each stage end). The ANN
+                  probe is one compiled call from the staged query to the
+                  probe list: fused=1, and `staged` says where the query
+                  was padded ("host" or "device", as stage_in's)
     queue_wait /  front-door requests only: admission latency and the
     split         coalesced-batch sub-span (callers, batch rows)
 

@@ -130,3 +130,22 @@ def test_frame_pool_sq_scan_compiles_for_v5e(one_chip, d):
             q, codes, lo, scale, valid, ids, frames, k_out, metric="l2",
             qsel=qsel, norms=None, interpret=False)
     _compile(fn, shapes, one_chip, "sq_scan_topk")
+
+
+def test_paged_plan_compiles_for_v5e(one_chip):
+    """The paged planner (`executor._paged_plan`) as `paged_search` calls
+    it on the SIFT-1M paged deployment: one L2 query, 10,000 centroids of
+    d = 128, 8 probes and a running top-k of 400 (top 100 at rerank
+    factor 4). One program from the staged query to the probe list."""
+    from repro.core import executor
+    shapes = [((1, 128), jnp.float32), ((1,), jnp.bool_),
+              ((K_PARTS, 128), jnp.float32), ((K_PARTS,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = executor._paged_plan.lower(
+        *args, n_probe=8, metric="l2", k_want=400, p_max=P_MAX).compile()
+    q, qmask, upart, qsel, run_s, run_i = compiled.out_info
+    assert (q.shape, qmask.shape) == ((1, 128), (1,))
+    assert (upart.shape, upart.dtype) == ((8,), jnp.int32)
+    assert (qsel.shape, qsel.dtype) == ((1, 8), jnp.bool_)
+    assert run_s.shape == run_i.shape == (1, 400)
