@@ -26,8 +26,8 @@ def main() -> None:
 
     from . import (bench_build, bench_e2e, bench_executor, bench_fleet,
                    bench_hybrid, bench_minibatch, bench_mqo, bench_obs,
-                   bench_paged, bench_quantized, bench_roofline,
-                   bench_serve, bench_updates)
+                   bench_paged, bench_quantized, bench_serve,
+                   bench_updates)
     sections = {
         "fig4_5_e2e": bench_e2e.main,
         "fig6_build": bench_build.main,
@@ -35,7 +35,6 @@ def main() -> None:
         "fig8_minibatch": bench_minibatch.main,
         "fig9_mqo": bench_mqo.main,
         "fig10_updates": bench_updates.main,
-        "roofline": bench_roofline.main,
         "executor": bench_executor.main,
         "quantized": bench_quantized.main,
         "paged": bench_paged.main,

@@ -1,11 +1,11 @@
 """MicroNN-TPU: disk/HBM-tiered updatable vector search (MicroNN, Apple
-2025) as a first-class feature of a multi-pod JAX LM framework.
+2025), an embeddable vector database whose scan tier lives in
+accelerator memory and whose durable store is SQLite.
 
 Public surface:
     repro.storage.MicroNN        -- the embeddable engine (paper Fig. 1)
     repro.core                   -- C1-C6 algorithm modules
-    repro.configs.get_arch       -- --arch registry (10 assigned archs)
-    repro.launch.dryrun          -- multi-pod dry-run + roofline
+    repro.serving.FrontDoor      -- concurrent, coalescing query front door
     repro.distributed            -- pod-scale distributed ANN search
     repro.fleet.Fleet            -- multi-tenant engines, one FramePool
 """

@@ -12,17 +12,16 @@
   monitor      -- index-quality tracking + maintenance triggers (C6)
   quantize     -- int8 scalar-quantization tier (codes + rerank contract)
   topk         -- running top-k + cross-device tournament merge
-  rag          -- kNN-LM integration with the model zoo
 """
 from . import (delta, hybrid, ivf, kmeans, maintenance, monitor, mqo,
-               optimizer, quantize, query, rag, search, topk)
+               optimizer, quantize, query, search, topk)
 from .query import Q, QuerySpec, ResultSet
 from .types import (DeltaStore, IVFConfig, IVFIndex, SearchResult,
                     INVALID_ID, pairwise_scores, normalize_if_cosine)
 
 __all__ = [
     "delta", "hybrid", "ivf", "kmeans", "maintenance", "monitor", "mqo",
-    "optimizer", "quantize", "query", "rag", "search", "topk",
+    "optimizer", "quantize", "query", "search", "topk",
     "Q", "QuerySpec", "ResultSet",
     "DeltaStore", "IVFConfig", "IVFIndex", "SearchResult", "INVALID_ID",
     "pairwise_scores", "normalize_if_cosine",

@@ -10,7 +10,6 @@ Module map -- who builds specs, who runs them:
     core/mqo.py         kwarg shim: mqo_search -> spec with a union cap
     core/optimizer.py   hybrid pre/post spec choice (paper Eqs. 1-3),
                         both arms issued through this executor
-    core/rag.py         kNN-LM retrieval -> ANN specs
     storage/engine.py   MicroNN.query(vecs, QuerySpec) -> run()
     distributed/        sharded_index phase 3 calls fused_scan directly
                         on each device's local partition shard

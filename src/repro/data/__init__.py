@@ -1,1 +1,1 @@
-from . import synthetic, tokens
+from . import synthetic

@@ -1,22 +1,2 @@
-"""Serving tier: the concurrent front door over MicroNN (frontdoor.py)
-and the continuous-batching LM decode engine (engine.py).
-
-`ServeEngine`/`Request` pull in the full model stack, so they load
-lazily (PEP 562) -- the storage layer can import the light FrontDoor
-module without dragging transformer weights into every embedded-engine
-process.
-"""
+"""Serving tier: the concurrent front door over MicroNN (frontdoor.py)."""
 from .frontdoor import FrontDoor, FrontDoorConfig, empty_stats
-
-_LAZY = ("Request", "ServeEngine")
-
-
-def __getattr__(name):
-    if name in _LAZY:
-        from . import engine as _engine
-        return getattr(_engine, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
-
-def __dir__():
-    return sorted(list(globals()) + list(_LAZY))

@@ -17,8 +17,8 @@ from repro.core.hybrid import And, Pred, compile_filter
 from repro.core.types import INVALID_ID, IVFConfig
 
 
-@pytest.fixture(scope="module")
-def exec_index():
+@pytest.fixture(scope="module", params=["l2", "ip", "cosine"])
+def exec_index(request):
     rng = np.random.default_rng(7)
     centers = rng.normal(size=(16, 24)).astype(np.float32) * 5
     X = (centers[rng.integers(0, 16, 1500)]
@@ -26,7 +26,7 @@ def exec_index():
     attrs = np.stack([rng.integers(0, 8, 1500),
                       rng.normal(size=1500) * 10], 1).astype(np.float32)
     cfg = IVFConfig(dim=24, target_partition_size=50, kmeans_iters=30,
-                    delta_capacity=128)
+                    delta_capacity=128, metric=request.param)
     idx = ivf.build_index(X, attrs=attrs, cfg=cfg)
     # live delta rows so the epilogue merge is exercised too
     nv = rng.normal(size=(10, 24)).astype(np.float32)

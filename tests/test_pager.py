@@ -235,14 +235,17 @@ def test_invalidate_forces_refetch(tmp_path):
 # -- paged engine: parity + invalidation through the write path --------------
 
 
-@pytest.fixture(scope="module", params=["none", "int8"])
+@pytest.fixture(scope="module", params=[
+    (metric, quant) for metric in ("l2", "ip", "cosine")
+    for quant in ("none", "int8")], ids="-".join)
 def paged_pair(request, tmp_path_factory):
     """(resident, paged) engines recovered from the same durable state."""
-    quant = request.param
+    metric, quant = request.param
     X = clustered_data(n=1500, dim=16, seed=8)
-    path = str(tmp_path_factory.mktemp("pager") / f"{quant}.db")
+    path = str(tmp_path_factory.mktemp("pager") / f"{metric}-{quant}.db")
     cfg = IVFConfig(dim=16, target_partition_size=50, kmeans_iters=15,
-                    delta_capacity=64, quantize=quant, rerank_factor=4)
+                    delta_capacity=64, quantize=quant, rerank_factor=4,
+                    metric=metric)
     eng = MicroNN(dim=16, n_attr=1, path=path, config=cfg)
     eng.upsert(np.arange(len(X)), X, np.ones((len(X), 1), np.float32))
     eng.build()
